@@ -17,7 +17,7 @@ hold them (the engines: ``self._step``, ``self._chunk``...).  Pass
 of just reporting it — benchmarks report, CI asserts via
 ``tools/check_bench.py --max-retraces``.
 
-``_cache_size`` is a private jax API (present on the pinned 0.4.x line);
+``_cache_size`` is a private jax API (present on the pinned 0.9.0);
 callables without it are skipped and listed in ``report.untracked`` so a
 jax upgrade degrades this to a no-op rather than an error.
 """

@@ -14,39 +14,15 @@ from __future__ import annotations
 import jax
 
 
-def abstract_mesh(shape, axes):
-    """Version-compatible ``jax.sharding.AbstractMesh`` factory.
-
-    JAX 0.4.35+ takes a tuple of (axis_name, size) pairs; earlier releases
-    took ``(shape, axis_names)`` positionally.  Spec-building tests and
-    dry-runs construct device-free meshes through this helper so they run
-    on either signature."""
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(zip(axes, shape)))
-    except TypeError:
-        return AbstractMesh(tuple(shape), tuple(axes))
-
-
-def _make_mesh(shape, axes):
-    """Version-compatible ``jax.make_mesh``: the helper only landed in
-    JAX 0.4.35, and CI's oldest-supported matrix leg (0.4.34, the last
-    pre-``AbstractMesh``-signature-change release) predates it."""
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(shape, axes)
-    from jax.experimental import mesh_utils
-    return jax.sharding.Mesh(mesh_utils.create_device_mesh(shape), axes)
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """1-device mesh for CPU tests/benches (same axis names as single-pod)."""
-    return _make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"))
 
 
 # TPU v5e hardware constants (roofline denominators; EXPERIMENTS.md §Roofline)

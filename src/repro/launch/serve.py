@@ -18,9 +18,10 @@ class), and ``--background N`` floods N low-priority long generations
 first so deadlined requests exercise freeze-native lane preemption
 (``--no-preempt`` to disable; see docs/serving.md).
 
-CPU/demo scale runs the tiny variant end-to-end; on a TPU slice the same
-driver binds the production mesh (launch/mesh.py) and the jitted steps carry
-the in/out shardings from launch/specs.py.
+Every engine runs on JAX's default device: ``--tiny`` on the CPU, the
+full-width configs on one accelerator.  ``model_config``, ``init_model`` and
+``build_engine`` are the construction path, shared with ``chip_smoke.py``;
+``enable_compile_cache`` keeps compiled programs across runs.
 
     PYTHONPATH=src python -m repro.launch.serve --arch llama3-8b --tiny \
         --requests 8 --tokens 128
@@ -30,18 +31,70 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import os
+import pathlib
 import time
+from typing import Optional
 
 import jax
 import numpy as np
 
 from repro.configs import get_config, list_archs
+from repro.configs.base import ModelConfig
 from repro.models import model as MD
 from repro.serving.config import ServingConfig
 from repro.serving.engine import (ContinuousEngine, Engine,
                                   PagedContinuousEngine)
 from repro.serving.sampling import SamplingParams
 from repro.serving.scheduler import Scheduler, StaticScheduler
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is the directory (JAX reads
+    it itself) and no other is set.  Otherwise the cache lives at the
+    fixed ``<checkout>/.jax_cache``: the path is part of the cache key, so
+    it must not move between runs."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(pathlib.Path(__file__).resolve().parents[3] / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def model_config(arch: str, *, tiny: bool = False,
+                 num_layers: Optional[int] = None,
+                 quantile_tau: float = 0.45,
+                 recovery: bool = True) -> ModelConfig:
+    """The served model: ``arch`` (or its ``-tiny`` variant), optionally
+    cut to its first ``num_layers`` layers, with this launcher's freeze
+    schedule (adaptive-tau quantile ``quantile_tau``; 0 = the paper's
+    fixed tau) and entropy-guided recovery on or off."""
+    cfg = get_config(arch + ("-tiny" if tiny else ""))
+    if num_layers is not None:
+        cfg = dataclasses.replace(cfg, num_layers=num_layers)
+    if quantile_tau > 0:
+        cfg = dataclasses.replace(cfg, freeze=dataclasses.replace(
+            cfg.freeze, tau_mode="quantile", quantile=quantile_tau,
+            window=16, k_soft=1.0, entropy_abs_threshold=1e9))
+    return dataclasses.replace(cfg, freeze=dataclasses.replace(
+        cfg.freeze, recovery_enabled=recovery))
+
+
+def init_model(cfg: ModelConfig, seed: int = 0):
+    """Random weights from ``seed``, made by one jitted program on the
+    default device, so no float32 copy of a full-width model is held."""
+    return jax.jit(MD.init_params, static_argnums=1)(
+        jax.random.PRNGKey(seed), cfg)
+
+
+def build_engine(cfg: ModelConfig, params, sv: ServingConfig):
+    """The continuous engine ``sv`` describes: ``PagedContinuousEngine``
+    when it sets ``max_active_pages``, else ``ContinuousEngine``."""
+    if sv.max_active_pages is not None:
+        return PagedContinuousEngine(cfg, params, serving=sv)
+    return ContinuousEngine(cfg, params, serving=sv)
 
 
 def _serve_http(args, mk_engine) -> None:
@@ -202,19 +255,18 @@ def main():
                          "prefill-chunk schedule, see docs/serving.md)")
     args = ap.parse_args()
 
-    cfg = get_config(args.arch + ("-tiny" if args.tiny else ""))
-    if args.quantile_tau > 0:
-        cfg = dataclasses.replace(cfg, freeze=dataclasses.replace(
-            cfg.freeze, tau_mode="quantile", quantile=args.quantile_tau,
-            window=16, k_soft=1.0, entropy_abs_threshold=1e9))
-    cfg = dataclasses.replace(cfg, freeze=dataclasses.replace(
-        cfg.freeze, recovery_enabled=args.recovery))
-    params = MD.init_params(jax.random.PRNGKey(0), cfg)
+    enable_compile_cache()
+    cfg = model_config(args.arch, tiny=args.tiny,
+                       quantile_tau=args.quantile_tau,
+                       recovery=args.recovery)
+    params = init_model(cfg)
     n = sum(x.size for x in jax.tree_util.tree_leaves(params))
     mode = "static" if args.static else \
         ("paged-continuous" if args.paged else "continuous")
+    dev = jax.devices()[0]
     print(f"arch={cfg.name} params={n/1e6:.1f}M "
-          f"freeze={not args.no_freeze} batching={mode}")
+          f"freeze={not args.no_freeze} batching={mode} "
+          f"device={dev.platform}:{dev.device_kind}")
 
     chaos = None
     if args.chaos_seed is not None:
@@ -233,9 +285,7 @@ def main():
                        kv_quant=args.kv_quant)
 
     def mk_engine():
-        if args.paged:
-            return PagedContinuousEngine(cfg, params, serving=sv)
-        return ContinuousEngine(cfg, params, serving=sv)
+        return build_engine(cfg, params, sv)
 
     if args.http is not None:
         _serve_http(args, mk_engine)

@@ -57,6 +57,13 @@ wrote some (metadata-only push otherwise), and speculatively uploads the
 top-priority stashed pages into per-lane device *staging slots* so an
 entropy-driven thaw becomes a page-table remap instead of a blocking
 upload (``core.paging.PagedController.stage_slots`` / ``staged_keys``).
+
+Every host->device upload goes through ``_upload``, which hands the
+device a private copy of the host buffer.  ``jnp.asarray`` /
+``device_put`` may alias a numpy buffer until the transfer completes,
+and the engines refill their per-step vectors and reused staging buffers
+while work dispatched from them can still be queued — an aliased upload
+would then read the next step's values.
 """
 from __future__ import annotations
 
@@ -82,6 +89,12 @@ from repro.serving.faults import ChaosConfig, Endpoint
 from repro.serving.sampling import (SamplingParams, lane_base_key,
                                     params_arrays, sample,
                                     sample_batched_perlane)
+
+
+def _upload(x) -> jax.Array:
+    """Host -> device upload of a buffer the engine may refill before the
+    dispatched work that reads it has run (see the module docstring)."""
+    return jnp.asarray(np.array(x))
 
 
 @dataclasses.dataclass
@@ -720,9 +733,9 @@ class _LaneEngineBase:
 
     def _lane_params(self):
         if self._lane_params_dev is None:
-            self._lane_params_dev = (jnp.asarray(self._temp),
-                                     jnp.asarray(self._topk),
-                                     jnp.asarray(self._topp))
+            self._lane_params_dev = (_upload(self._temp),
+                                     _upload(self._topk),
+                                     _upload(self._topp))
         return self._lane_params_dev
 
     def _left_padded(self, prompt: np.ndarray, sp: int) -> np.ndarray:
@@ -977,7 +990,7 @@ class ContinuousEngine(_LaneEngineBase):
         lane_state = MD.init_decode_state(self.cfg, 1, self.max_seq)
         self._note_kv_peak(lane_state.cache_k.nbytes + lane_state.cache_v.nbytes)
         logits, lane_state = self._prefill(
-            self.params, batch={"tokens": jnp.asarray(toks)}, state=lane_state)
+            self.params, batch={"tokens": _upload(toks)}, state=lane_state)
         self.state = self._write_lane(self.state, lane_state, jnp.int32(lane))
         if self.offloader is not None:
             self.offloader.drop_lane(lane)
@@ -1022,8 +1035,8 @@ class ContinuousEngine(_LaneEngineBase):
             return finished
         self._note_kv_peak()
         logits, self.state, info = self._step(
-            self.params, token=jnp.asarray(self.tok),
-            pos=jnp.asarray(self.pos), step=jnp.asarray(self.step),
+            self.params, token=_upload(self.tok),
+            pos=_upload(self.pos), step=_upload(self.step),
             state=self.state)
         self.wall_step += 1
         # enqueue per-lane sampling right behind the step, then start the
@@ -1033,8 +1046,8 @@ class ContinuousEngine(_LaneEngineBase):
                 "rr_request")
         arrays = dict(
             {k: info[k] for k in keys if k in info},
-            toks=self._sample(logits, jnp.asarray(self.lane_keys),
-                              jnp.asarray(self.step), *self._lane_params()))
+            toks=self._sample(logits, _upload(self.lane_keys),
+                              _upload(self.step), *self._lane_params()))
         offload = self.offloader is not None
         if offload:
             # fold the offload controller's freeze-mask read into the same
@@ -1238,7 +1251,7 @@ class ContinuousEngine(_LaneEngineBase):
         self._note_kv_peak(lane_state.cache_k.nbytes
                            + lane_state.cache_v.nbytes)
         _, lane_state = self._prefill(
-            self.params, batch={"tokens": jnp.asarray(toks)},
+            self.params, batch={"tokens": _upload(toks)},
             state=lane_state)
         self.state = self._write_lane(self.state, lane_state,
                                       jnp.int32(lane))
@@ -1535,7 +1548,7 @@ class PagedContinuousEngine(_LaneEngineBase):
     def _pull_lanes(self, lanes: List[int]) -> Tuple[dict, dict]:
         m = len(lanes)
         dev = self._gather_lanes(self._state_arrs(),
-                                 jnp.asarray(self._padded_idx(lanes)))
+                                 _upload(self._padded_idx(lanes)))
         t0 = time.perf_counter()
         # the ONE batched D2H for all boundary lanes + layers, recorded in
         # TransferStats below — the pull every per-lane slice rides on.
@@ -1583,8 +1596,8 @@ class PagedContinuousEngine(_LaneEngineBase):
         # half-donated scatter (re-running it would read freed buffers)
         def _dispatch():
             return self._scatter_lanes(self._state_arrs(fields),
-                                       jnp.asarray(idx),
-                                       tuple(jnp.asarray(v) for v in vals))
+                                       _upload(idx),
+                                       tuple(_upload(v) for v in vals))
         arrs = self.ep_push.call(_dispatch) if self.ep_push is not None \
             else _dispatch()
         upd = dict(zip(fields, arrs))
@@ -1728,7 +1741,7 @@ class PagedContinuousEngine(_LaneEngineBase):
             while c * 2 <= rem:
                 c *= 2
         c = min(c, rem)
-        chunk = jnp.asarray(pp.toks[:, pp.done:pp.done + c])
+        chunk = _upload(pp.toks[:, pp.done:pp.done + c])
         pp.logits, pp.scratch = self._chunk(
             self.params, tokens=chunk, state=pp.scratch,
             pos0=jnp.int32(pp.done))
@@ -1860,18 +1873,18 @@ class PagedContinuousEngine(_LaneEngineBase):
             live[decode_lanes] = True
             self._note_kv_peak(self._scratch_bytes())
             logits, self.state, info = self._step(
-                self.params, token=jnp.asarray(self.tok),
-                pos=jnp.asarray(self.pos), step=jnp.asarray(self.step),
-                tail_slot=jnp.asarray(self.tail_slot), state=self.state,
-                live=jnp.asarray(live))
+                self.params, token=_upload(self.tok),
+                pos=_upload(self.pos), step=_upload(self.step),
+                tail_slot=_upload(self.tail_slot), state=self.state,
+                live=_upload(live))
             self.wall_step += 1
             keys = ("n_active_slots_lane", "n_frozen_pages_lane", "entropy",
                     "spike", "level", "ema_entropy", "rr_request",
                     "thaw_request")
             arrays = dict(
                 {k: info[k] for k in keys if k in info},
-                toks=self._sample(logits, jnp.asarray(self.lane_keys),
-                                  jnp.asarray(self.step),
+                toks=self._sample(logits, _upload(self.lane_keys),
+                                  _upload(self.step),
                                   *self._lane_params()))
             self.ring.push({"kind": "step", "active": list(decode_lanes),
                             "poison": self._poison_lane(decode_lanes)},
@@ -2064,8 +2077,8 @@ class PagedContinuousEngine(_LaneEngineBase):
             for j, (l, lane, src, dst) in enumerate(chunk):
                 ls[j], lanes[j], srcs[j], dsts[j] = l, lane, src, dst
             self.state = self._remap_copy(
-                self.state, jnp.asarray(ls), jnp.asarray(lanes),
-                jnp.asarray(srcs), jnp.asarray(dsts))
+                self.state, _upload(ls), _upload(lanes),
+                _upload(srcs), _upload(dsts))
 
     def _maybe_prefetch(self, decode_lanes: List[int]) -> None:
         """Dispatch speculative staging uploads for lanes trending toward
@@ -2166,9 +2179,9 @@ class PagedContinuousEngine(_LaneEngineBase):
             # staged, and installs fall back to the sync upload path
             def _dispatch():
                 return self._stage_write(
-                    self.state, jnp.int32(lane), jnp.asarray(slots),
-                    jnp.asarray(k_buf), jnp.asarray(v_buf),
-                    jnp.asarray(valid))
+                    self.state, jnp.int32(lane), _upload(slots),
+                    _upload(k_buf), _upload(v_buf),
+                    _upload(valid))
             if self.ep_stage is not None:
                 out = self.ep_stage.call(_dispatch)
                 if out is Endpoint.FAILED:

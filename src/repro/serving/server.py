@@ -134,6 +134,9 @@ class AsyncServingEngine:
         self.sched = sched
         self.stream_capacity = stream_capacity
         self.unhandled_exceptions = 0
+        # the newest of them, for a caller that must fail on it (the loop
+        # itself keeps serving the other streams)
+        self.last_exception: Optional[BaseException] = None
         self.n_paused = 0
         self.n_resumed = 0
         self._streams: Dict[int, _StreamState] = {}
@@ -192,15 +195,15 @@ class AsyncServingEngine:
             try:
                 self._apply_ops()
                 self._pump_all()
-            except Exception:
-                self.unhandled_exceptions += 1
+            except Exception as e:
+                self._unhandled(e)
             if not self._running and not self._ops:
                 return
             if self.sched.queue or self.sched.busy:
                 try:
                     await loop.run_in_executor(None, self.sched.step)
-                except Exception:
-                    self.unhandled_exceptions += 1
+                except Exception as e:
+                    self._unhandled(e)
                 # yield so handlers queued behind the step get a slice
                 await asyncio.sleep(0)
             else:
@@ -226,10 +229,14 @@ class AsyncServingEngine:
                 else:                      # pragma: no cover
                     raise AssertionError(kind)
             except Exception as e:
-                self.unhandled_exceptions += 1
+                self._unhandled(e)
                 if not fut.done():
                     fut.set_exception(e)
         self._apply_backpressure()
+
+    def _unhandled(self, e: BaseException) -> None:
+        self.unhandled_exceptions += 1
+        self.last_exception = e
 
     def _cancel(self, uid: int) -> bool:
         st = self._streams.get(uid)
@@ -405,8 +412,8 @@ class ServingServer:
             await self._route(method, path, headers, body, reader, writer)
         except (ConnectionError, asyncio.IncompleteReadError):
             pass
-        except Exception:
-            self.engine.unhandled_exceptions += 1
+        except Exception as e:
+            self.engine._unhandled(e)
         finally:
             try:
                 writer.close()

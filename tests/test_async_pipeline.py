@@ -90,6 +90,25 @@ class TestParityAsyncVsSync:
             assert a.telemetry.total_kv == b.telemetry.total_kv
             assert a.telemetry.offloaded_tokens == b.telemetry.offloaded_tokens
 
+    def test_async_runs_are_reproducible(self, thaw_rewind_cfg):
+        """Repeated async runs of one trace emit identical tokens.  The
+        engine refills its host vectors and staging buffers while work
+        dispatched from them is still queued; an upload that aliased the
+        host buffer would make the tokens differ from run to run."""
+        cfg, params = thaw_rewind_cfg
+
+        def run():
+            eng = PagedContinuousEngine(
+                cfg, params, max_seq=256, n_lanes=2, max_active_pages=6,
+                prefill_chunk=16, rewind_cooldown=12, async_pipeline=True,
+                burst_prefill=False)
+            return [r.result for r in _serve(eng, cfg, [(48, 70), (20, 50)])]
+
+        first = run()
+        for _ in range(2):
+            for a, b in zip(first, run()):
+                np.testing.assert_array_equal(a, b)
+
     def test_contiguous_with_offload(self, tiny_f32):
         """The contiguous engine shares the ring (incl. the folded-in
         offload freeze-mask fetch): async and sync must agree on tokens

@@ -8,10 +8,10 @@ import subprocess
 import sys
 
 import pytest
+from jax.sharding import AbstractMesh
 
 from repro.configs import INPUT_SHAPES, get_config, list_archs
 from repro.launch import specs as SP
-from repro.launch.mesh import abstract_mesh
 
 SRC = str(pathlib.Path(__file__).resolve().parents[1] / "src")
 
@@ -20,7 +20,7 @@ class TestSpecs:
     def test_all_combos_build(self):
         """Every (arch x shape) either builds a StepBundle or is an
         explicit documented skip — nothing falls through."""
-        mesh = abstract_mesh((16, 16), ("data", "model"))
+        mesh = AbstractMesh((16, 16), ("data", "model"))
         built = skipped = 0
         for arch in list_archs():
             cfg = get_config(arch)
@@ -34,20 +34,20 @@ class TestSpecs:
         assert built == 39 and skipped == 1   # whisper long_500k only
 
     def test_long_500k_uses_paged_path(self):
-        mesh = abstract_mesh((16, 16), ("data", "model"))
+        mesh = AbstractMesh((16, 16), ("data", "model"))
         b = SP.build_step(get_config("mistral-large-123b"),
                           INPUT_SHAPES["long_500k"], mesh)
         assert b.static["kind"] == "decode_paged"
         assert b.static["active_tokens"] == SP.LONG_CONTEXT_ACTIVE_TOKENS
 
     def test_rwkv_long_500k_is_o1_state(self):
-        mesh = abstract_mesh((16, 16), ("data", "model"))
+        mesh = AbstractMesh((16, 16), ("data", "model"))
         b = SP.build_step(get_config("rwkv6-1.6b"),
                           INPUT_SHAPES["long_500k"], mesh)
         assert b.static["kind"] == "decode"   # recurrent state, no paging
 
     def test_infer_mode_heuristic(self):
-        mesh = abstract_mesh((16, 16), ("data", "model"))
+        mesh = AbstractMesh((16, 16), ("data", "model"))
         small = SP.param_mode(get_config("llama3-8b"),
                               INPUT_SHAPES["decode_32k"], mesh)
         big = SP.param_mode(get_config("jamba-1.5-large-398b"),

@@ -2,15 +2,15 @@
 no devices needed)."""
 import jax
 import numpy as np
+from jax.sharding import AbstractMesh
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_config
-from repro.launch.mesh import abstract_mesh
 from repro.models import model as MD
 from repro.sharding import rules as RU
 
-SP = abstract_mesh((16, 16), ("data", "model"))
-MP = abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+SP = AbstractMesh((16, 16), ("data", "model"))
+MP = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def leaves_with_paths(tree):
