@@ -5,7 +5,8 @@ its runtime backstop — it watches the actual jit compile caches while a
 workload runs and asserts they stop growing once warm.  The invariant
 auditor (``audit_controller`` / ``audit_boundary``) is the data-structure
 counterpart: pool/stash/lane consistency checks the serving engine runs
-at boundary ticks under its ``debug_invariants`` flag.
+at boundary ticks under its ``debug_invariants`` flag.  ``spans`` reads
+the serving path's ``repro:`` profiler spans back out of a capture.
 """
 from .invariants import InvariantViolation, audit_boundary, audit_controller
 from .runtime import RetraceError, TraceReport, trace_guard

@@ -8,7 +8,8 @@ loop asynchronous with respect to the host:
 * ``TransferStats`` — accounting for every host<->device transfer the
   engine issues, split into *blocking* (the host stalled on data that was
   not already in flight) and *async* (issued early, consumed after the
-  device had time to produce it).  ``host_blocked_fraction`` — the share
+  device had time to produce it).  Byte counts are the bytes that crossed
+  the bus: the whole padded buffer of a batched transfer.  ``host_blocked_fraction`` — the share
   of engine steps that stalled on at least one blocking transfer — is the
   benchmark's pipeline-health metric: the synchronous path sits at 1.0 by
   construction, the async pipeline only blocks at page-boundary ticks.
@@ -35,6 +36,8 @@ import dataclasses
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
+import jax
+
 
 def _nbytes(x) -> int:
     try:
@@ -51,8 +54,10 @@ class TransferStats:
     ``device_put`` whose data was not already in flight (boundary-tick pool
     pulls, un-prefetched thaw uploads, depth-0 ring pops).  *Async*
     transfers were issued ahead of use (ring fetches, speculative thaw
-    staging) — the host may still wait on them at consume time, but the
-    wait is overlap-compensated and recorded separately as ``waited_s``.
+    staging) — the host may still wait on them at consume time, which a
+    profiler capture shows as the ``repro:ring.wait`` span.  Byte counts
+    are what crossed the bus: the whole padded buffer of a batched
+    transfer, at the width it was sent.
     """
     blocking_d2h: int = 0
     blocking_h2d: int = 0
@@ -61,7 +66,6 @@ class TransferStats:
     d2h_bytes: int = 0
     h2d_bytes: int = 0
     blocked_s: float = 0.0      # host time inside blocking transfers
-    waited_s: float = 0.0       # host time waiting on async-issued data
     steps: int = 0              # engine steps observed (begin/end bracket)
     blocked_steps: int = 0      # steps with >= 1 blocking transfer
     _step_open: bool = dataclasses.field(default=False, repr=False)
@@ -98,15 +102,13 @@ class TransferStats:
         if self._step_open:
             self._step_blocked = True
 
-    def note_async(self, nbytes: int, d2h: bool, seconds: float = 0.0
-                   ) -> None:
+    def note_async(self, nbytes: int, d2h: bool) -> None:
         if d2h:
             self.async_d2h += 1
             self.d2h_bytes += nbytes
         else:
             self.async_h2d += 1
             self.h2d_bytes += nbytes
-        self.waited_s += seconds
 
     # ---- derived metrics -------------------------------------------- #
     @property
@@ -123,7 +125,6 @@ class TransferStats:
             "d2h_bytes": self.d2h_bytes,
             "h2d_bytes": self.h2d_bytes,
             "blocked_s": round(self.blocked_s, 4),
-            "waited_s": round(self.waited_s, 4),
             "steps": self.steps,
             "blocked_steps": self.blocked_steps,
             "host_blocked_fraction": round(self.host_blocked_fraction, 4),
@@ -177,21 +178,22 @@ class FetchRing:
             return None
         import numpy as np
         meta, arrays = self._entries.pop(0)
-        t0 = time.perf_counter()
+        nbytes = sum(_nbytes(v) for v in arrays.values())
 
         def _materialize():
             return {k: np.asarray(v) for k, v in arrays.items()}
 
-        if self.endpoint is not None:
-            host = self.endpoint.call(_materialize)
-        else:
-            host = _materialize()
-        dt = time.perf_counter() - t0
-        nbytes = sum(_nbytes(v) for v in host.values())
+        with jax.profiler.TraceAnnotation("repro:ring.wait", bytes=nbytes):
+            t0 = time.perf_counter()
+            if self.endpoint is not None:
+                host = self.endpoint.call(_materialize)
+            else:
+                host = _materialize()
+            dt = time.perf_counter() - t0
         if self.depth == 0:
             self.stats.note_blocking(nbytes, d2h=True, seconds=dt)
         else:
-            self.stats.note_async(nbytes, d2h=True, seconds=dt)
+            self.stats.note_async(nbytes, d2h=True)
         return meta, host
 
     def drain(self):

@@ -97,6 +97,13 @@ def _upload(x) -> jax.Array:
     return jnp.asarray(np.array(x))
 
 
+def _named(fn, name: str):
+    """Name a ``functools.partial`` for ``jax.jit``: its program is then
+    ``jit_<name>`` in a profiler trace, not ``jit__unknown``."""
+    fn.__name__ = name
+    return fn
+
+
 @dataclasses.dataclass
 class GenerationResult:
     tokens: np.ndarray                 # (B, n_generated)
@@ -771,11 +778,13 @@ class _LaneEngineBase:
         is synchronous), so host decisions are applied in the same order
         in both modes."""
         finished: List[Request] = []
-        for meta, host in self.ring.drain():
-            if meta["kind"] == "admit":
-                finished.extend(self._commit_admit(meta, host))
-            else:
-                finished.extend(self._commit_step(meta, host))
+        with jax.profiler.TraceAnnotation("repro:engine.drain"):
+            for meta, host in self.ring.drain():
+                with jax.profiler.TraceAnnotation("repro:engine.commit"):
+                    if meta["kind"] == "admit":
+                        finished.extend(self._commit_admit(meta, host))
+                    else:
+                        finished.extend(self._commit_step(meta, host))
         return finished
 
     def flush(self) -> List[Request]:
@@ -1387,32 +1396,38 @@ class PagedContinuousEngine(_LaneEngineBase):
         self.S_stage = sv.speculative_slots if (speculative_thaw
                                                 and self.enable_freeze) else 0
         self.P_total = self.P + self.S_stage
-        self._step = jax.jit(functools.partial(
+        # every program is jitted under a name of its own, so a profiler
+        # trace tells them apart (``jit_<name>`` on the device's program
+        # line)
+        self._step = jax.jit(_named(functools.partial(
             MD.decode_step_paged, cfg=cfg, freeze_cfg=self.fcfg,
             enable_freeze=self.enable_freeze, reserved_slots=self.S_stage),
-            donate_argnames=("state",))
-        self._rewind = jax.jit(
+            "decode_step_paged"), donate_argnames=("state",))
+        self._rewind = jax.jit(_named(
             functools.partial(MD.rewind_paged_lane, cfg, page=self.page),
-            donate_argnames=("state",))
-        self._chunk = jax.jit(functools.partial(MD.prefill_chunk, cfg=cfg),
-                              donate_argnames=("state",))
-        self._reset_lane = jax.jit(functools.partial(MD.reset_paged_lane, cfg),
-                                   donate_argnames=("state",))
+            "rewind_paged_lane"), donate_argnames=("state",))
+        self._chunk = jax.jit(
+            _named(functools.partial(MD.prefill_chunk, cfg=cfg),
+                   "prefill_chunk"), donate_argnames=("state",))
+        self._reset_lane = jax.jit(
+            _named(functools.partial(MD.reset_paged_lane, cfg),
+                   "reset_paged_lane"), donate_argnames=("state",))
         # batched boundary-tick DMA: ONE gather + device_get pulls every
         # boundary lane's pool slice (all layers stacked), ONE scatter +
         # device_put pushes them back.  The lane-index vector is padded to
         # n_lanes (repeating the first lane) so each tuple shape compiles
         # exactly once; duplicate scatter indices write identical columns.
-        self._gather_lanes = jax.jit(
-            lambda arrs, idx: tuple(jnp.take(a, idx, axis=1) for a in arrs))
-        self._scatter_lanes = jax.jit(
-            lambda arrs, idx, vals: tuple(
-                a.at[:, idx].set(v.astype(a.dtype))
-                for a, v in zip(arrs, vals)),
-            donate_argnums=(0,))
+        def gather_lanes(arrs, idx):
+            return tuple(jnp.take(a, idx, axis=1) for a in arrs)
+        self._gather_lanes = jax.jit(gather_lanes)
+
+        def scatter_lanes(arrs, idx, vals):
+            return tuple(a.at[:, idx].set(v.astype(a.dtype))
+                         for a, v in zip(arrs, vals))
+        self._scatter_lanes = jax.jit(scatter_lanes, donate_argnums=(0,))
         # speculative staging write: scatter one page of K/V per layer into
         # the lane's staging slots (valid=False layers are a no-op)
-        def _stage_write_fn(state, lane, slots, new_k, new_v, valid):
+        def stage_write(state, lane, slots, new_k, new_v, valid):
             li = jnp.arange(state.k.shape[0])
             slots = jnp.maximum(slots, 0)
             sel = valid[:, None, None, None]
@@ -1423,32 +1438,29 @@ class PagedContinuousEngine(_LaneEngineBase):
             v = state.v.at[li, lane, slots].set(
                 jnp.where(sel, new_v.astype(state.v.dtype), cur_v))
             return state._replace(k=k, v=v)
-        self._stage_write = jax.jit(_stage_write_fn,
-                                    donate_argnames=("state",))
+        self._stage_write = jax.jit(stage_write, donate_argnames=("state",))
         # staged installs: ONE device-side batched copy staging slots ->
         # target slots per tick (padded to a fixed width so it compiles
         # once; padding rows copy slot 0 onto itself — a no-op)
-        def _remap_copy_fn(state, layers, lanes, srcs, dsts):
+        def remap_copy(state, layers, lanes, srcs, dsts):
             k = state.k.at[layers, lanes, dsts].set(
                 state.k[layers, lanes, srcs])
             v = state.v.at[layers, lanes, dsts].set(
                 state.v[layers, lanes, srcs])
             return state._replace(k=k, v=v)
-        self._remap_copy = jax.jit(_remap_copy_fn,
-                                   donate_argnames=("state",))
+        self._remap_copy = jax.jit(remap_copy, donate_argnames=("state",))
         self._remap_width = 8
         # preemption resume: the pool slice rides _push_lanes, but the
         # recovery ladder is per-lane (B,) state outside the pool fields —
         # restore one lane's scalars with a tiny donated scatter
-        def _set_rec_fn(state, lane, ema, level, calm, seen):
+        def set_recovery(state, lane, ema, level, calm, seen):
             r = state.recovery
             return state._replace(recovery=RecoveryState(
                 ema_entropy=r.ema_entropy.at[lane].set(ema),
                 level=r.level.at[lane].set(level),
                 calm_steps=r.calm_steps.at[lane].set(calm),
                 steps_seen=r.steps_seen.at[lane].set(seen)))
-        self._set_recovery = jax.jit(_set_rec_fn,
-                                     donate_argnames=("state",))
+        self._set_recovery = jax.jit(set_recovery, donate_argnames=("state",))
         self.state = MD.init_paged_decode_state(
             cfg, self.n_lanes, max_active_pages, staging_slots=self.S_stage)
         self.L_attn = max(self.state.page_table.shape[0], 1)
@@ -1499,15 +1511,16 @@ class PagedContinuousEngine(_LaneEngineBase):
                    for pp in self.prefills.values())
 
     # ---------------- device <-> host pool transfer ---------------- #
-    # Only the affected lanes' pool slices cross the host<->device boundary
-    # — and they cross it BATCHED: a boundary tick with any number of lanes
-    # issues exactly one device_get (a jitted gather over the padded
-    # lane-index vector stacks all lanes and layers) and one device_put
-    # (a donated scatter).  Pulled data lands in reused host staging
-    # buffers (pinned memory on a real TPU); the push carries K/V only
-    # when the controller actually wrote some (kv_dirty) — a tick that
-    # only flipped metadata (page-table remaps, freeze counters) moves a
-    # few KB, not the pool.
+    # A boundary tick's pool slices cross the host<->device boundary
+    # BATCHED: a tick with any number of lanes issues exactly one
+    # device_get (a jitted gather over the lane-index vector, padded to
+    # n_lanes, stacks all lanes and layers) and one device_put (a donated
+    # scatter) — so each moves all n_lanes columns, whatever the number of
+    # boundary lanes, and TransferStats counts them all.  Pulled data lands
+    # in reused host staging buffers (pinned memory on a real TPU); the
+    # push carries K/V only when the controller actually wrote some
+    # (kv_dirty) — a tick that only flipped metadata (page-table remaps,
+    # freeze counters) moves the metadata fields, not the pool.
     # page_quant / kv_scales travel with BOTH field sets: a metadata-only
     # push (staged-remap tick) must still land the target slots' quant
     # flags + scales — the remap copies the quantized payload device-side,
@@ -1529,46 +1542,37 @@ class PagedContinuousEngine(_LaneEngineBase):
         idx[:len(lanes)] = lanes
         return idx
 
-    @staticmethod
-    def _quant_packing_savings(pool: dict) -> int:
-        """Bytes a real TPU transfer would NOT move for this pool slice:
-        quantized mapped pages cross the bus at 1 byte/elem (K and V), not
-        at the pool dtype's width.  The CPU reference path moves the
-        widened payload, so the gauges subtract the packing delta to model
-        the deployable transfer size (docs/quantization.md)."""
-        pq = pool.get("page_quant")
-        if pq is None:
-            return 0
-        n = int(((np.asarray(pq) != 0)
-                 & (np.asarray(pool["page_table"]) >= 0)).sum())
-        k = pool["k"]
-        page_elems = int(np.prod(k.shape[3:]))
-        return n * page_elems * (k.dtype.itemsize - 1) * 2
-
     def _pull_lanes(self, lanes: List[int]) -> Tuple[dict, dict]:
         m = len(lanes)
-        dev = self._gather_lanes(self._state_arrs(),
-                                 _upload(self._padded_idx(lanes)))
-        t0 = time.perf_counter()
-        # the ONE batched D2H for all boundary lanes + layers, recorded in
-        # TransferStats below — the pull every per-lane slice rides on.
-        # Under chaos the endpoint fronts it: injected failures burn
-        # retries BEFORE device_get runs (must-succeed — the tick cannot
-        # proceed without the pool bytes), so the real pull runs once
-        if self.ep_pull is not None:
-            # hotpath: ok(single batched boundary-tick pull, counted via note_blocking)
-            host = self.ep_pull.call(jax.device_get, dev)
-        else:
-            # hotpath: ok(single batched boundary-tick pull, counted via note_blocking)
-            host = jax.device_get(dev)
-        dt = time.perf_counter() - t0
+        with jax.profiler.TraceAnnotation("repro:engine.pull_lanes",
+                                          lanes=m) as span:
+            dev = self._gather_lanes(self._state_arrs(),
+                                     _upload(self._padded_idx(lanes)))
+            t0 = time.perf_counter()
+            # the ONE batched D2H for all boundary lanes + layers, recorded
+            # in TransferStats below — the pull every per-lane slice rides
+            # on.  Under chaos the endpoint fronts it: injected failures
+            # burn retries BEFORE device_get runs (must-succeed — the tick
+            # cannot proceed without the pool bytes), so the real pull
+            # runs once
+            if self.ep_pull is not None:
+                # hotpath: ok(single batched boundary-tick pull, counted via note_blocking)
+                host = self.ep_pull.call(jax.device_get, dev)
+            else:
+                # hotpath: ok(single batched boundary-tick pull, counted via note_blocking)
+                host = jax.device_get(dev)
+            dt = time.perf_counter() - t0
+            # all n_lanes padded columns crossed the bus, not only the m
+            # boundary lanes' ones
+            nbytes = sum(a.nbytes for a in host)
+            span.set_metadata(bytes=nbytes)
+        self.stats.note_blocking(nbytes, d2h=True, seconds=dt)
         names = self._POOL_FIELDS + self._FZ_FIELDS
-        out = {}
-        for name, arr in zip(names, host):
-            out[name] = self.staging.put(f"pull_{name}_{m}", arr[:, :m])
-        self.stats.note_blocking(sum(a.nbytes for a in out.values())
-                                 - self._quant_packing_savings(out),
-                                 d2h=True, seconds=dt)
+        with jax.profiler.TraceAnnotation("repro:engine.unpack") as span:
+            out = {}
+            for name, arr in zip(names, host):
+                out[name] = self.staging.put(f"pull_{name}_{m}", arr[:, :m])
+            span.set_metadata(bytes=sum(a.nbytes for a in out.values()))
         return ({f: out[f] for f in self._POOL_FIELDS},
                 {f: out[f] for f in self._FZ_FIELDS})
 
@@ -1580,26 +1584,30 @@ class PagedContinuousEngine(_LaneEngineBase):
             self.n_kv_pushes += 1
         fields = (self._POOL_FIELDS + self._FZ_FIELDS) if kv \
             else self._META_FIELDS
-        vals = []
-        nbytes = 0
-        for f in fields:
-            src = pool[f] if f in pool else fstate[f]
-            buf = self.staging.buf(f"push_{f}", (src.shape[0], self.n_lanes)
-                                   + src.shape[2:], src.dtype)
-            buf[:, :m] = src
-            if m < self.n_lanes:        # duplicate scatter columns must
-                buf[:, m:] = src[:, :1]  # carry identical data
-            vals.append(buf)
-            nbytes += src.nbytes
-        # the dispatch closure runs exactly once per endpoint call —
-        # injected failures are simulated before it, never around a
-        # half-donated scatter (re-running it would read freed buffers)
-        def _dispatch():
-            return self._scatter_lanes(self._state_arrs(fields),
-                                       _upload(idx),
-                                       tuple(_upload(v) for v in vals))
-        arrs = self.ep_push.call(_dispatch) if self.ep_push is not None \
-            else _dispatch()
+        with jax.profiler.TraceAnnotation("repro:engine.push_lanes", lanes=m,
+                                          kv=int(kv)) as span:
+            vals = []
+            for f in fields:
+                src = pool[f] if f in pool else fstate[f]
+                buf = self.staging.buf(f"push_{f}",
+                                       (src.shape[0], self.n_lanes)
+                                       + src.shape[2:], src.dtype)
+                buf[:, :m] = src
+                if m < self.n_lanes:        # duplicate scatter columns must
+                    buf[:, m:] = src[:, :1]  # carry identical data
+                vals.append(buf)
+            # every padded column crosses the bus
+            nbytes = sum(v.nbytes for v in vals)
+            span.set_metadata(bytes=nbytes)
+            # the dispatch closure runs exactly once per endpoint call —
+            # injected failures are simulated before it, never around a
+            # half-donated scatter (re-running it would read freed buffers)
+            def _dispatch():
+                return self._scatter_lanes(self._state_arrs(fields),
+                                           _upload(idx),
+                                           tuple(_upload(v) for v in vals))
+            arrs = self.ep_push.call(_dispatch) if self.ep_push is not None \
+                else _dispatch()
         upd = dict(zip(fields, arrs))
         fz = PageFreezeState(*(upd.get(f, getattr(self.state.freeze, f))
                                for f in self._FZ_FIELDS))
@@ -1609,7 +1617,6 @@ class PagedContinuousEngine(_LaneEngineBase):
         # the K/V of a metadata-only push never crossed the bus: remapped
         # staging slots already hold their page data on device
         if kv:
-            nbytes -= self._quant_packing_savings(pool)
             self.stats.note_blocking(nbytes, d2h=False)
         else:
             self.stats.note_async(nbytes, d2h=False)
@@ -1741,16 +1748,21 @@ class PagedContinuousEngine(_LaneEngineBase):
             while c * 2 <= rem:
                 c *= 2
         c = min(c, rem)
-        chunk = _upload(pp.toks[:, pp.done:pp.done + c])
-        pp.logits, pp.scratch = self._chunk(
-            self.params, tokens=chunk, state=pp.scratch,
-            pos0=jnp.int32(pp.done))
+        with jax.profiler.TraceAnnotation("repro:engine.prefill",
+                                          uid=pp.req.uid, tokens=c):
+            chunk = _upload(pp.toks[:, pp.done:pp.done + c])
+            pp.logits, pp.scratch = self._chunk(
+                self.params, tokens=chunk, state=pp.scratch,
+                pos0=jnp.int32(pp.done))
         pp.done += c
         self.events.append({"event": "prefill_chunk", "uid": pp.req.uid,
                             "lane": lane, "wall_step": self.wall_step,
                             "done": pp.done, "total": pp.sp})
         if pp.done >= pp.sp:
-            self._install(lane)
+            with jax.profiler.TraceAnnotation(
+                    "repro:engine.install", uid=pp.req.uid,
+                    pages=-(-pp.sp // self.page)):
+                self._install(lane)
 
     def _install(self, lane: int) -> None:
         """Repack the finished scratch prefill into pages and install them
@@ -1857,60 +1869,94 @@ class PagedContinuousEngine(_LaneEngineBase):
         jitted paged decode step over the resident lanes with its fetch
         pushed asynchronously behind it, speculative thaw staging, and one
         prefill chunk for every admission in flight.  Returns retired
-        requests (from the drain; same-call with ``async_pipeline=False``)."""
-        self.stats.begin_step()
-        self._ring_guard()
-        finished = self._retired_backlog + self._drain_ring()
-        self._retired_backlog = []
-        decode_lanes = [i for i, l in enumerate(self.lanes)
-                        if l.request is not None
-                        and (i not in self.prefills or self.prefills[i].over)]
-        if decode_lanes:
-            boundary = [i for i in decode_lanes if self.pos[i] % self.page == 0]
-            if boundary:
-                self._boundary_tick(boundary)
-            live = np.zeros(self.n_lanes, bool)
-            live[decode_lanes] = True
-            self._note_kv_peak(self._scratch_bytes())
-            logits, self.state, info = self._step(
-                self.params, token=_upload(self.tok),
-                pos=_upload(self.pos), step=_upload(self.step),
-                tail_slot=_upload(self.tail_slot), state=self.state,
-                live=_upload(live))
-            self.wall_step += 1
-            keys = ("n_active_slots_lane", "n_frozen_pages_lane", "entropy",
-                    "spike", "level", "ema_entropy", "rr_request",
-                    "thaw_request")
-            arrays = dict(
-                {k: info[k] for k in keys if k in info},
-                toks=self._sample(logits, _upload(self.lane_keys),
-                                  _upload(self.step),
-                                  *self._lane_params()))
-            self.ring.push({"kind": "step", "active": list(decode_lanes),
-                            "poison": self._poison_lane(decode_lanes)},
-                           arrays)
-            # start copying likely-thaw pages into the staging slots while
-            # the step computes — by the time an FR thaw fires at a
-            # boundary tick, its pages install as a page-table remap
-            self._maybe_prefetch(decode_lanes)
+        requests (from the drain; same-call with ``async_pipeline=False``).
 
-        # ---- chunked prefill: one chunk per admission in flight ---- #
-        for lane in list(self.prefills):
-            self._prefill_tick(lane, busy=bool(decode_lanes))
-        if self.ring.depth == 0:
-            finished += self._drain_ring()
-        if decode_lanes:
-            self.stats.end_step()
-        else:
-            self.stats.cancel_step()
-        return finished
+        In a profiler capture the call is the ``repro:engine.step`` span
+        (``wall_step``, decode ``lanes``, ``boundary`` lanes), with the
+        spans of docs/serving.md ("Spans in a profiler capture") inside."""
+        with jax.profiler.TraceAnnotation("repro:engine.step",
+                                          wall_step=self.wall_step) as span:
+            self.stats.begin_step()
+            self._ring_guard()
+            finished = self._retired_backlog + self._drain_ring()
+            self._retired_backlog = []
+            decode_lanes = [i for i, l in enumerate(self.lanes)
+                            if l.request is not None
+                            and (i not in self.prefills
+                                 or self.prefills[i].over)]
+            boundary = [i for i in decode_lanes
+                        if self.pos[i] % self.page == 0]
+            span.set_metadata(lanes=len(decode_lanes),
+                              boundary=len(boundary))
+            if decode_lanes:
+                if boundary:
+                    self._boundary_tick(boundary)
+                with jax.profiler.TraceAnnotation("repro:engine.decode"):
+                    live = np.zeros(self.n_lanes, bool)
+                    live[decode_lanes] = True
+                    self._note_kv_peak(self._scratch_bytes())
+                    logits, self.state, info = self._step(
+                        self.params, token=_upload(self.tok),
+                        pos=_upload(self.pos), step=_upload(self.step),
+                        tail_slot=_upload(self.tail_slot), state=self.state,
+                        live=_upload(live))
+                    self.wall_step += 1
+                    keys = ("n_active_slots_lane", "n_frozen_pages_lane",
+                            "entropy", "spike", "level", "ema_entropy",
+                            "rr_request", "thaw_request")
+                    arrays = dict(
+                        {k: info[k] for k in keys if k in info},
+                        toks=self._sample(logits, _upload(self.lane_keys),
+                                          _upload(self.step),
+                                          *self._lane_params()))
+                    self.ring.push({"kind": "step",
+                                    "active": list(decode_lanes),
+                                    "poison": self._poison_lane(decode_lanes)},
+                                   arrays)
+                # start copying likely-thaw pages into the staging slots
+                # while the step computes — by the time an FR thaw fires at
+                # a boundary tick, its pages install as a page-table remap
+                self._maybe_prefetch(decode_lanes)
+
+            # ---- chunked prefill: one chunk per admission in flight ---- #
+            for lane in list(self.prefills):
+                self._prefill_tick(lane, busy=bool(decode_lanes))
+            if self.ring.depth == 0:
+                finished += self._drain_ring()
+            if decode_lanes:
+                self.stats.end_step()
+            else:
+                self.stats.cancel_step()
+            return finished
 
     def _boundary_tick(self, boundary: List[int]) -> None:
         """Page-boundary maintenance for `boundary` lanes: one batched
         pull, the host controller pass (timer swaps, pending thaws, tail
         allocation with the force-free backstop), one batched push, then
-        the queued device-side staging remaps."""
-        self.n_boundary_ticks += 1
+        the queued device-side staging remaps.  Spans: ``repro:engine.tick``
+        around it all, ``repro:kv.tick`` around the controller pass."""
+        with jax.profiler.TraceAnnotation("repro:engine.tick",
+                                          lanes=len(boundary)):
+            self.n_boundary_ticks += 1
+            pool, fstate = self._pull_lanes(boundary)
+            with jax.profiler.TraceAnnotation("repro:kv.tick"):
+                self._controller_pass(boundary, pool, fstate)
+            if self.debug_invariants:
+                # the one moment the host holds a coherent cross-structure
+                # view: post-controller-pass, pre-push
+                from repro.analysis import audit_boundary
+                audit_boundary(self.ctl, pool, fstate, range(len(boundary)),
+                               lane_ids={bi: i
+                                         for bi, i in enumerate(boundary)})
+            self._note_stash_peak()
+            self._push_lanes(pool, fstate, boundary, kv=self.ctl.kv_dirty)
+            self._run_remaps()
+
+    def _controller_pass(self, boundary: List[int], pool: dict,
+                         fstate: dict) -> None:
+        """The host controller's share of a boundary tick, on the pulled
+        slices: timer swaps, pending thaws, and tail allocation with the
+        force-free backstop."""
         # graceful-degradation ladder, engine-applied rungs: under stash
         # pressure first reclaim redundant host copies of resident pages
         # (stage 1+, parity-free), then deepen the forced-freeze timers so
@@ -1925,7 +1971,6 @@ class PagedContinuousEngine(_LaneEngineBase):
             self.robust["ladder_deepen"] += 1
         self.ctl.begin_tick()
         self._prune_staged()
-        pool, fstate = self._pull_lanes(boundary)
         keep = {bi: self._keep_gids(i) for bi, i in enumerate(boundary)}
         thaw = tuple(bi for bi, i in enumerate(boundary)
                      if i in self.pending_thaws)
@@ -1953,15 +1998,6 @@ class PagedContinuousEngine(_LaneEngineBase):
                        " — freezing is disabled, so nothing swaps "
                        "out; admission should have rejected this"))
             self.tail_slot[:, i] = slots
-        if self.debug_invariants:
-            # the one moment the host holds a coherent cross-structure
-            # view: post-controller-pass, pre-push
-            from repro.analysis import audit_boundary
-            audit_boundary(self.ctl, pool, fstate, range(len(boundary)),
-                           lane_ids={bi: i for bi, i in enumerate(boundary)})
-        self._note_stash_peak()
-        self._push_lanes(pool, fstate, boundary, kv=self.ctl.kv_dirty)
-        self._run_remaps()
 
     def _commit_step(self, meta: Dict[str, Any], host: Dict[str, Any]
                      ) -> List[Request]:
@@ -2067,18 +2103,21 @@ class PagedContinuousEngine(_LaneEngineBase):
         remaps = self.ctl.pending_remaps
         self.ctl.pending_remaps = []
         W = self._remap_width
-        for i in range(0, len(remaps), W):
-            chunk = remaps[i:i + W]
-            ls, lanes = np.zeros(W, np.int32), np.zeros(W, np.int32)
-            # padding rows self-copy a staging slot — never a real remap's
-            # destination, so the batched scatter stays conflict-free
-            srcs = np.full(W, self.P, np.int32)
-            dsts = np.full(W, self.P, np.int32)
-            for j, (l, lane, src, dst) in enumerate(chunk):
-                ls[j], lanes[j], srcs[j], dsts[j] = l, lane, src, dst
-            self.state = self._remap_copy(
-                self.state, _upload(ls), _upload(lanes),
-                _upload(srcs), _upload(dsts))
+        with jax.profiler.TraceAnnotation("repro:engine.remap",
+                                          n=len(remaps)):
+            for i in range(0, len(remaps), W):
+                chunk = remaps[i:i + W]
+                ls, lanes = np.zeros(W, np.int32), np.zeros(W, np.int32)
+                # padding rows self-copy a staging slot — never a real
+                # remap's destination, so the batched scatter stays
+                # conflict-free
+                srcs = np.full(W, self.P, np.int32)
+                dsts = np.full(W, self.P, np.int32)
+                for j, (l, lane, src, dst) in enumerate(chunk):
+                    ls[j], lanes[j], srcs[j], dsts[j] = l, lane, src, dst
+                self.state = self._remap_copy(
+                    self.state, _upload(ls), _upload(lanes),
+                    _upload(srcs), _upload(dsts))
 
     def _maybe_prefetch(self, decode_lanes: List[int]) -> None:
         """Dispatch speculative staging uploads for lanes trending toward
@@ -2091,34 +2130,35 @@ class PagedContinuousEngine(_LaneEngineBase):
         uploads per step on a deep stack.  The H2D copies are dispatched
         asynchronously behind the decode step; they never change page
         tables, so a misprediction costs bandwidth, not correctness."""
-        if not self.S_stage:
-            return
-        if self.stash_pressure >= self.ladder_cfg.deny_prefetch:
-            # ladder stage 1: deny speculative prefetch under stash
-            # pressure (staging is pure optimization — thaws fall back to
-            # the sync upload path, token-identically)
-            self.robust["ladder_deny"] += 1
-            return
-        if self.ep_stage is not None and not self.ep_stage.allow():
-            # tripped stage breaker: speculative staging stays disabled
-            # until the breaker's op-count cooldown re-closes it (same
-            # token-identical sync-upload fallback)
-            return
-        # stage for lanes that WILL thaw (request pending, boundary tick
-        # not yet reached) and for lanes trending within one spike of FR
-        # (urgency >= WR) — looser gating buys little and costs a state
-        # dispatch per staged page
-        from repro.core.recovery import WR
-        cands = [i for i in decode_lanes
-                 if i in self.pending_thaws or self._urgency[i] >= WR]
-        cands.sort(key=lambda i: (i not in self.pending_thaws,
-                                  -self._urgency[i]))
-        budget = self.S_stage
-        for lane in cands:
-            while budget and self._prefetch_lane(lane):
-                budget -= 1
-            if not budget:
+        with jax.profiler.TraceAnnotation("repro:engine.prefetch"):
+            if not self.S_stage:
                 return
+            if self.stash_pressure >= self.ladder_cfg.deny_prefetch:
+                # ladder stage 1: deny speculative prefetch under stash
+                # pressure (staging is pure optimization — thaws fall back to
+                # the sync upload path, token-identically)
+                self.robust["ladder_deny"] += 1
+                return
+            if self.ep_stage is not None and not self.ep_stage.allow():
+                # tripped stage breaker: speculative staging stays disabled
+                # until the breaker's op-count cooldown re-closes it (same
+                # token-identical sync-upload fallback)
+                return
+            # stage for lanes that WILL thaw (request pending, boundary tick
+            # not yet reached) and for lanes trending within one spike of FR
+            # (urgency >= WR) — looser gating buys little and costs a state
+            # dispatch per staged page
+            from repro.core.recovery import WR
+            cands = [i for i in decode_lanes
+                     if i in self.pending_thaws or self._urgency[i] >= WR]
+            cands.sort(key=lambda i: (i not in self.pending_thaws,
+                                      -self._urgency[i]))
+            budget = self.S_stage
+            for lane in cands:
+                while budget and self._prefetch_lane(lane):
+                    budget -= 1
+                if not budget:
+                    return
 
     def _prefetch_lane(self, lane: int) -> bool:
         from repro.core.recovery import thaw_priority
@@ -2192,9 +2232,9 @@ class PagedContinuousEngine(_LaneEngineBase):
             for l in range(self.L_attn):
                 if valid[l]:
                     self.ctl.staged_keys[(l, lane, gid)] = int(slots[l])
-            # count what the host store actually holds — a quantized page
-            # crosses the bus packed (1 byte/elem), not pool-width
-            self.stats.note_async(sent, d2h=False)
+            # the whole pool-dtype buffers cross the bus, every layer's
+            # page whether it was stashed or not
+            self.stats.note_async(k_buf.nbytes + v_buf.nbytes, d2h=False)
             return True
         return False
 

@@ -64,6 +64,7 @@ import math
 import time
 from typing import Any, Dict, List, Optional, Union
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -605,10 +606,11 @@ class Scheduler:
         self._push(snap)
 
     def _schedule(self) -> None:
-        self._apply_aging()
-        self._maybe_shed()
-        self._maybe_preempt()
-        self._admit_free()
+        with jax.profiler.TraceAnnotation("repro:sched.schedule"):
+            self._apply_aging()
+            self._maybe_shed()
+            self._maybe_preempt()
+            self._admit_free()
 
     # ---------------- serving loop ---------------- #
     @property
@@ -629,44 +631,45 @@ class Scheduler:
     def step(self) -> List[int]:
         """One scheduling pass + one engine step; returns completed uids.
         The building block for external drivers with timed arrivals
-        (``benchmarks/scheduling.py``)."""
-        self._schedule()
-        if not self.busy:
-            return []
-        t0 = self.clock()
-        retired = self.engine.step_once()
-        dt = self.clock() - t0
-        self._step_s = dt if self._step_s is None \
-            else 0.7 * self._step_s + 0.3 * dt
-        if self.tenancy is not None:
-            # charge each tenant the committed tokens its lanes gained
-            # this step (delta-based: rewinds shrink `generated` and are
-            # simply not refunded)
-            for l in self.engine.lanes:
-                if l.request is not None:
-                    self.tenancy.note_progress(
-                        l.request.tenant, l.request.uid, len(l.generated))
-        for snap in self.engine.drain_suspended():
-            self.metrics[snap.req.uid]["preempted"] += 1
-            self.n_preemptions += 1
+        (``benchmarks/scheduling.py``).  Span: ``repro:sched.step``."""
+        with jax.profiler.TraceAnnotation("repro:sched.step"):
+            self._schedule()
+            if not self.busy:
+                return []
+            t0 = self.clock()
+            retired = self.engine.step_once()
+            dt = self.clock() - t0
+            self._step_s = dt if self._step_s is None \
+                else 0.7 * self._step_s + 0.3 * dt
             if self.tenancy is not None:
-                self.tenancy.note_progress(snap.req.tenant, snap.req.uid,
-                                           len(snap.generated))
-                self.tenancy.note_release(snap.req.tenant, snap.req.uid)
-            self._push(snap)
-        out = []
-        now = self.clock()
-        for req in retired:
-            self.done[req.uid] = req
-            m = self.metrics[req.uid]
-            m["finish_t"] = now
-            dl = m["deadline_t"]
-            m["deadline_hit"] = None if dl is None else bool(now <= dl)
-            if self.tenancy is not None:
-                self.tenancy.note_done(req.tenant, req.uid,
-                                       int(len(req.result)))
-            out.append(req.uid)
-        return out
+                # charge each tenant the committed tokens its lanes gained
+                # this step (delta-based: rewinds shrink `generated` and are
+                # simply not refunded)
+                for l in self.engine.lanes:
+                    if l.request is not None:
+                        self.tenancy.note_progress(
+                            l.request.tenant, l.request.uid, len(l.generated))
+            for snap in self.engine.drain_suspended():
+                self.metrics[snap.req.uid]["preempted"] += 1
+                self.n_preemptions += 1
+                if self.tenancy is not None:
+                    self.tenancy.note_progress(snap.req.tenant, snap.req.uid,
+                                               len(snap.generated))
+                    self.tenancy.note_release(snap.req.tenant, snap.req.uid)
+                self._push(snap)
+            out = []
+            now = self.clock()
+            for req in retired:
+                self.done[req.uid] = req
+                m = self.metrics[req.uid]
+                m["finish_t"] = now
+                dl = m["deadline_t"]
+                m["deadline_hit"] = None if dl is None else bool(now <= dl)
+                if self.tenancy is not None:
+                    self.tenancy.note_done(req.tenant, req.uid,
+                                           int(len(req.result)))
+                out.append(req.uid)
+            return out
 
     def run_once(self) -> List[int]:
         """Serve until at least one request completes (lanes refill from

@@ -51,6 +51,7 @@ import asyncio
 import json
 from typing import Any, Dict, List, Optional, Union
 
+import jax
 import numpy as np
 
 from repro.serving.engine import LaneSnapshot, Request, RequestStatus
@@ -193,8 +194,9 @@ class AsyncServingEngine:
         loop = asyncio.get_running_loop()
         while True:
             try:
-                self._apply_ops()
-                self._pump_all()
+                with jax.profiler.TraceAnnotation("repro:serve.ops"):
+                    self._apply_ops()
+                    self._pump_all()
             except Exception as e:
                 self._unhandled(e)
             if not self._running and not self._ops:

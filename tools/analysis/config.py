@@ -31,6 +31,7 @@ REPO_CONFIG = Config(
         "PagedContinuousEngine.step_once",
         "PagedContinuousEngine._commit_step",
         "PagedContinuousEngine._boundary_tick",
+        "PagedContinuousEngine._controller_pass",
         "PagedContinuousEngine._pull_lanes",
         "PagedContinuousEngine._push_lanes",
         "PagedContinuousEngine._prefill_tick",
