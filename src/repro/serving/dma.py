@@ -9,10 +9,10 @@ loop asynchronous with respect to the host:
   engine issues, split into *blocking* (the host stalled on data that was
   not already in flight) and *async* (issued early, consumed after the
   device had time to produce it).  Byte counts are the bytes that crossed
-  the bus: the whole padded buffer of a batched transfer.  ``host_blocked_fraction`` — the share
-  of engine steps that stalled on at least one blocking transfer — is the
-  benchmark's pipeline-health metric: the synchronous path sits at 1.0 by
-  construction, the async pipeline only blocks at page-boundary ticks.
+  the bus: each array as it was sent.  ``host_blocked_fraction`` — the
+  share of engine steps that stalled on at least one blocking transfer —
+  is the benchmark's pipeline-health metric: the synchronous path sits at
+  1.0 by construction, the async pipeline only blocks at page-boundary ticks.
 
 * ``FetchRing`` — the double-buffered device->host fetch ring.  At step N
   the engine pushes the step's device arrays (sampled tokens, entropy /
@@ -24,8 +24,8 @@ loop asynchronous with respect to the host:
   by a blocking pop — so both modes share one code path and differ only in
   when the host waits.
 
-* ``HostStaging`` — reused host-side staging buffers for the batched
-  boundary-tick swap DMA.  On TPU these would be pinned host allocations
+* ``HostStaging`` — reused host-side staging buffers for the
+  boundary-tick pull and the speculative thaw uploads.  On TPU these would be pinned host allocations
   (the DMA engine requirement for async H2D); here they model the reuse:
   one buffer per transfer role, reallocated only when shapes change, so
   steady-state ticks allocate nothing.
@@ -56,8 +56,8 @@ class TransferStats:
     transfers were issued ahead of use (ring fetches, speculative thaw
     staging) — the host may still wait on them at consume time, which a
     profiler capture shows as the ``repro:ring.wait`` span.  Byte counts
-    are what crossed the bus: the whole padded buffer of a batched
-    transfer, at the width it was sent.
+    are what crossed the bus: each array at the width it was sent,
+    padding included.
     """
     blocking_d2h: int = 0
     blocking_h2d: int = 0
@@ -208,8 +208,7 @@ class HostStaging:
     ``buf(name, shape, dtype)`` returns a numpy buffer that persists across
     calls; it is reallocated only when the requested shape/dtype changes,
     so the steady-state boundary tick reuses the same allocation for its
-    pull/push staging.  ``put(name, src)`` copies ``src`` into the named
-    buffer and returns it.
+    pull.
     """
 
     def __init__(self):
@@ -221,12 +220,6 @@ class HostStaging:
         if b is None or b.shape != tuple(shape) or b.dtype != np.dtype(dtype):
             b = np.empty(shape, dtype)
             self._bufs[name] = b
-        return b
-
-    def put(self, name: str, src):
-        import numpy as np
-        b = self.buf(name, src.shape, src.dtype)
-        np.copyto(b, src)
         return b
 
     @property

@@ -50,13 +50,14 @@ Output tokens are bit-identical whenever the prefill chunk split is
 deterministic (``burst_prefill=False``): the modes admit on different
 wall calls, and the load-adaptive burst split would change
 flash-attention summation order — float rounding, not decisions.  The
-paged engine additionally batches each boundary tick's pool slices into
-ONE device_get / device_put pair across all boundary lanes and layers
-(reused host staging buffers), pushes K/V back only when the tick actually
-wrote some (metadata-only push otherwise), and speculatively uploads the
-top-priority stashed pages into per-lane device *staging slots* so an
-entropy-driven thaw becomes a page-table remap instead of a blocking
-upload (``core.paging.PagedController.stage_slots`` / ``staged_keys``).
+paged engine additionally pulls each boundary tick's pool slices, only
+the boundary lanes' columns, with ONE device_get across those lanes and
+layers (reused host staging buffers), pushes K/V back only when the tick
+actually wrote some (metadata-only push otherwise), and speculatively
+uploads the top-priority stashed pages into per-lane device *staging
+slots* so an entropy-driven thaw becomes a page-table remap instead of a
+blocking upload (``core.paging.PagedController.stage_slots`` /
+``staged_keys``).
 
 Every host->device upload goes through ``_upload``, which hands the
 device a private copy of the host buffer.  ``jnp.asarray`` /
@@ -76,6 +77,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.configs.base import FreezeConfig, ModelConfig
 from repro.core import quant
@@ -1323,8 +1325,8 @@ class PagedContinuousEngine(_LaneEngineBase):
 
     * **Async DMA pipeline** (``async_pipeline=True``, the default) — the
       per-step fetch rides the double-buffered ring (module docstring),
-      every boundary tick is ONE batched device_get/device_put pair with
-      metadata-only pushes when no K/V moved, and ``speculative_slots``
+      every boundary tick pulls its lanes with ONE device_get and pushes
+      them back, metadata-only when no K/V moved, and ``speculative_slots``
       staging slots per (layer, lane) hold prefetched likely-thaw pages
       (``thaw_urgency`` trend + ``thaw_priority`` ranking) so an FR thaw
       installs as a page-table remap plus a device-side copy instead of a
@@ -1412,19 +1414,20 @@ class PagedContinuousEngine(_LaneEngineBase):
         self._reset_lane = jax.jit(
             _named(functools.partial(MD.reset_paged_lane, cfg),
                    "reset_paged_lane"), donate_argnames=("state",))
-        # batched boundary-tick DMA: ONE gather + device_get pulls every
-        # boundary lane's pool slice (all layers stacked), ONE scatter +
-        # device_put pushes them back.  The lane-index vector is padded to
-        # n_lanes (repeating the first lane) so each tuple shape compiles
-        # exactly once; duplicate scatter indices write identical columns.
-        def gather_lanes(arrs, idx):
-            return tuple(jnp.take(a, idx, axis=1) for a in arrs)
-        self._gather_lanes = jax.jit(gather_lanes)
+        # lane DMA, one lane per call: the lane index is a traced scalar,
+        # so each program compiles once whatever the number of lanes a
+        # transfer moves, and only the asked lanes' columns cross the bus
+        def gather_lane(arrs, lane):
+            return tuple(lax.dynamic_index_in_dim(a, lane, axis=1,
+                                                  keepdims=False)
+                         for a in arrs)
+        self._gather_lane = jax.jit(gather_lane)
 
-        def scatter_lanes(arrs, idx, vals):
-            return tuple(a.at[:, idx].set(v.astype(a.dtype))
-                         for a, v in zip(arrs, vals))
-        self._scatter_lanes = jax.jit(scatter_lanes, donate_argnums=(0,))
+        def scatter_lane(arrs, lane, vals):
+            return tuple(lax.dynamic_update_index_in_dim(
+                a, v.astype(a.dtype), lane, axis=1)
+                for a, v in zip(arrs, vals))
+        self._scatter_lane = jax.jit(scatter_lane, donate_argnums=(0,))
         # speculative staging write: scatter one page of K/V per layer into
         # the lane's staging slots (valid=False layers are a no-op)
         def stage_write(state, lane, slots, new_k, new_v, valid):
@@ -1481,7 +1484,7 @@ class PagedContinuousEngine(_LaneEngineBase):
         self.prefills: Dict[int, _PendingPrefill] = {}
         self._urgency = np.zeros(self.n_lanes, np.float32)  # thaw trend/lane
         self.n_boundary_ticks = 0   # boundary maintenance passes (each one
-                                    # batched pull + one push)
+                                    # pull + one push of its lanes)
         self.n_kv_pushes = 0        # pushes that had to carry pool K/V
 
     @property
@@ -1511,16 +1514,15 @@ class PagedContinuousEngine(_LaneEngineBase):
                    for pp in self.prefills.values())
 
     # ---------------- device <-> host pool transfer ---------------- #
-    # A boundary tick's pool slices cross the host<->device boundary
-    # BATCHED: a tick with any number of lanes issues exactly one
-    # device_get (a jitted gather over the lane-index vector, padded to
-    # n_lanes, stacks all lanes and layers) and one device_put (a donated
-    # scatter) — so each moves all n_lanes columns, whatever the number of
-    # boundary lanes, and TransferStats counts them all.  Pulled data lands
-    # in reused host staging buffers (pinned memory on a real TPU); the
-    # push carries K/V only when the controller actually wrote some
-    # (kv_dirty) — a tick that only flipped metadata (page-table remaps,
-    # freeze counters) moves the metadata fields, not the pool.
+    # A transfer moves exactly the lanes it is asked for.  The pull
+    # dispatches one gather per lane (all layers stacked) and waits on ONE
+    # device_get over all of them, so their copies overlap; the push runs
+    # one donated scatter per lane.  TransferStats counts the columns that
+    # crossed.  Pulled data lands in reused host staging buffers (pinned
+    # memory on a real TPU); the push carries K/V only when the controller
+    # actually wrote some (kv_dirty) — a tick that only flipped metadata
+    # (page-table remaps, freeze counters) moves the metadata fields, not
+    # the pool.
     # page_quant / kv_scales travel with BOTH field sets: a metadata-only
     # push (staged-remap tick) must still land the target slots' quant
     # flags + scales — the remap copies the quantized payload device-side,
@@ -1537,24 +1539,18 @@ class PagedContinuousEngine(_LaneEngineBase):
         return tuple(getattr(st, f) if hasattr(st, f)
                      else getattr(st.freeze, f) for f in fields)
 
-    def _padded_idx(self, lanes: List[int]) -> np.ndarray:
-        idx = np.full(self.n_lanes, lanes[0], np.int32)
-        idx[:len(lanes)] = lanes
-        return idx
-
     def _pull_lanes(self, lanes: List[int]) -> Tuple[dict, dict]:
         m = len(lanes)
         with jax.profiler.TraceAnnotation("repro:engine.pull_lanes",
                                           lanes=m) as span:
-            dev = self._gather_lanes(self._state_arrs(),
-                                     _upload(self._padded_idx(lanes)))
+            arrs = self._state_arrs()
+            dev = [self._gather_lane(arrs, jnp.int32(i)) for i in lanes]
             t0 = time.perf_counter()
-            # the ONE batched D2H for all boundary lanes + layers, recorded
-            # in TransferStats below — the pull every per-lane slice rides
-            # on.  Under chaos the endpoint fronts it: injected failures
-            # burn retries BEFORE device_get runs (must-succeed — the tick
-            # cannot proceed without the pool bytes), so the real pull
-            # runs once
+            # the ONE blocking D2H for all asked lanes + layers, recorded
+            # in TransferStats below.  Under chaos the endpoint fronts it:
+            # injected failures burn retries BEFORE device_get runs
+            # (must-succeed — the tick cannot proceed without the pool
+            # bytes), so the real pull runs once
             if self.ep_pull is not None:
                 # hotpath: ok(single batched boundary-tick pull, counted via note_blocking)
                 host = self.ep_pull.call(jax.device_get, dev)
@@ -1562,16 +1558,19 @@ class PagedContinuousEngine(_LaneEngineBase):
                 # hotpath: ok(single batched boundary-tick pull, counted via note_blocking)
                 host = jax.device_get(dev)
             dt = time.perf_counter() - t0
-            # all n_lanes padded columns crossed the bus, not only the m
-            # boundary lanes' ones
-            nbytes = sum(a.nbytes for a in host)
+            nbytes = sum(a.nbytes for cols in host for a in cols)
             span.set_metadata(bytes=nbytes)
         self.stats.note_blocking(nbytes, d2h=True, seconds=dt)
         names = self._POOL_FIELDS + self._FZ_FIELDS
         with jax.profiler.TraceAnnotation("repro:engine.unpack") as span:
             out = {}
-            for name, arr in zip(names, host):
-                out[name] = self.staging.put(f"pull_{name}_{m}", arr[:, :m])
+            for f, name in enumerate(names):
+                col = host[0][f]
+                buf = self.staging.buf(f"pull_{name}_{m}",
+                                       (col.shape[0], m) + col.shape[1:],
+                                       col.dtype)
+                out[name] = np.stack([cols[f] for cols in host], axis=1,
+                                     out=buf)
             span.set_metadata(bytes=sum(a.nbytes for a in out.values()))
         return ({f: out[f] for f in self._POOL_FIELDS},
                 {f: out[f] for f in self._FZ_FIELDS})
@@ -1579,33 +1578,26 @@ class PagedContinuousEngine(_LaneEngineBase):
     def _push_lanes(self, pool: dict, fstate: dict, lanes: List[int],
                     kv: bool = True) -> None:
         m = len(lanes)
-        idx = self._padded_idx(lanes)
         if kv:
             self.n_kv_pushes += 1
         fields = (self._POOL_FIELDS + self._FZ_FIELDS) if kv \
             else self._META_FIELDS
         with jax.profiler.TraceAnnotation("repro:engine.push_lanes", lanes=m,
                                           kv=int(kv)) as span:
-            vals = []
-            for f in fields:
-                src = pool[f] if f in pool else fstate[f]
-                buf = self.staging.buf(f"push_{f}",
-                                       (src.shape[0], self.n_lanes)
-                                       + src.shape[2:], src.dtype)
-                buf[:, :m] = src
-                if m < self.n_lanes:        # duplicate scatter columns must
-                    buf[:, m:] = src[:, :1]  # carry identical data
-                vals.append(buf)
-            # every padded column crosses the bus
-            nbytes = sum(v.nbytes for v in vals)
+            # (L, m, ...) host arrays, column j for lanes[j]
+            srcs = [pool[f] if f in pool else fstate[f] for f in fields]
+            nbytes = sum(src.nbytes for src in srcs)
             span.set_metadata(bytes=nbytes)
             # the dispatch closure runs exactly once per endpoint call —
             # injected failures are simulated before it, never around a
             # half-donated scatter (re-running it would read freed buffers)
             def _dispatch():
-                return self._scatter_lanes(self._state_arrs(fields),
-                                           _upload(idx),
-                                           tuple(_upload(v) for v in vals))
+                arrs = self._state_arrs(fields)
+                for j, lane in enumerate(lanes):
+                    arrs = self._scatter_lane(
+                        arrs, jnp.int32(lane),
+                        tuple(_upload(src[:, j]) for src in srcs))
+                return arrs
             arrs = self.ep_push.call(_dispatch) if self.ep_push is not None \
                 else _dispatch()
         upd = dict(zip(fields, arrs))
@@ -1864,12 +1856,13 @@ class PagedContinuousEngine(_LaneEngineBase):
         """One engine call of the async pipeline: drain the previous
         step's fetch-ring entry (telemetry, thaw requests, page rewinds,
         token commits, retirement), then per-lane page-boundary
-        maintenance (ONE batched pull, host swap tick, pending thaws, tail
-        allocation, ONE batched push — metadata-only if no K/V moved), a
-        jitted paged decode step over the resident lanes with its fetch
-        pushed asynchronously behind it, speculative thaw staging, and one
-        prefill chunk for every admission in flight.  Returns retired
-        requests (from the drain; same-call with ``async_pipeline=False``).
+        maintenance (one pull of the boundary lanes, host swap tick,
+        pending thaws, tail allocation, one push of the same lanes —
+        metadata-only if no K/V moved), a jitted paged decode step over
+        the resident lanes with its fetch pushed asynchronously behind it,
+        speculative thaw staging, and one prefill chunk for every
+        admission in flight.  Returns retired requests (from the drain;
+        same-call with ``async_pipeline=False``).
 
         In a profiler capture the call is the ``repro:engine.step`` span
         (``wall_step``, decode ``lanes``, ``boundary`` lanes), with the
@@ -1930,11 +1923,12 @@ class PagedContinuousEngine(_LaneEngineBase):
             return finished
 
     def _boundary_tick(self, boundary: List[int]) -> None:
-        """Page-boundary maintenance for `boundary` lanes: one batched
-        pull, the host controller pass (timer swaps, pending thaws, tail
-        allocation with the force-free backstop), one batched push, then
-        the queued device-side staging remaps.  Spans: ``repro:engine.tick``
-        around it all, ``repro:kv.tick`` around the controller pass."""
+        """Page-boundary maintenance for `boundary` lanes: one pull of
+        their columns, the host controller pass (timer swaps, pending
+        thaws, tail allocation with the force-free backstop), one push of
+        the same columns, then the queued device-side staging remaps.
+        Spans: ``repro:engine.tick`` around it all, ``repro:kv.tick``
+        around the controller pass."""
         with jax.profiler.TraceAnnotation("repro:engine.tick",
                                           lanes=len(boundary)):
             self.n_boundary_ticks += 1
@@ -2304,7 +2298,7 @@ class PagedContinuousEngine(_LaneEngineBase):
 
         The snapshot owns (1) the lane's full pool slice — K/V pages,
         page table, slot masks and page-freeze counters, pulled in the
-        same ONE batched transfer a boundary tick uses — (2) the lane's
+        same pull a boundary tick uses — (2) the lane's
         recovery-ladder scalars, and (3) every host-stashed page, *moved
         out of* the ``PagedController`` store (``export_lane``) so
         reassigning the lane cannot ``drop_lane`` them.  ``resume_lane``

@@ -332,9 +332,10 @@ class TestDmaPrimitives:
 
     def test_staging_buffers_are_reused(self):
         st = HostStaging()
-        a = st.put("x", np.arange(6, dtype=np.float32).reshape(2, 3))
-        b = st.put("x", np.zeros((2, 3), np.float32))
-        assert a is b                       # same allocation, new contents
-        assert b.sum() == 0
+        a = st.buf("x", (2, 3), np.float32)
+        a[:] = 1
+        b = st.buf("x", (2, 3), np.float32)
+        assert a is b                       # same allocation, kept contents
+        assert b.sum() == 6
         c = st.buf("x", (4, 3), np.float32)  # shape change -> realloc
         assert c is not b

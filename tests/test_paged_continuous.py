@@ -393,3 +393,139 @@ class TestBoundedPool:
         assert int(np.asarray((eng.state.page_table >= 0).sum())) == 0
         assert not eng.ctl.frozen_meta
         assert eng.kv_device_bytes == eng.state.k.nbytes + eng.state.v.nbytes
+
+
+class TestLaneTransfer:
+    """The boundary tick's pull and push move exactly the lanes asked
+    for: the other lanes' columns never leave the device, and one program
+    per direction serves any number of lanes."""
+
+    @pytest.fixture(scope="class")
+    def tiny_freeze(self):
+        cfg = get_config("llama3-8b-tiny")
+        fc = dataclasses.replace(cfg.freeze, page_size=8, window=8,
+                                 tau_mode="quantile", quantile=0.6,
+                                 k_soft=1.0, recovery_enabled=False)
+        cfg = dataclasses.replace(cfg, freeze=fc, dtype="float32")
+        return cfg, MD.init_params(jax.random.PRNGKey(0), cfg)
+
+    @staticmethod
+    def _engine(tiny_freeze):
+        from repro.serving.config import ServingConfig
+        cfg, params = tiny_freeze
+        return PagedContinuousEngine(cfg, params, serving=ServingConfig(
+            max_seq=128, n_lanes=4, max_active_pages=5, prefill_chunk=16))
+
+    def _busy(self, tiny_freeze):
+        """A 4-lane engine with every lane decoding and pages frozen, its
+        fetch ring drained."""
+        eng = self._engine(tiny_freeze)
+        s = Scheduler(eng)
+        rng = np.random.RandomState(3)
+        for sp in (32, 16, 24, 8):
+            s.submit(rng.randint(0, eng.cfg.vocab_size, size=sp), 60,
+                     SamplingParams.greedy())
+        for _ in range(30):
+            s.step()
+        eng.flush()
+        assert all(l.request is not None for l in eng.lanes)
+        assert eng.ctl.n_swap_out > 0
+        return eng
+
+    @staticmethod
+    def _leaves(eng):
+        return [np.array(a) for a in jax.tree_util.tree_leaves(eng.state)]
+
+    def test_round_trip_leaves_the_state_bit_identical(self, tiny_freeze):
+        eng = self._busy(tiny_freeze)
+        before = self._leaves(eng)
+        pool, fstate = eng._pull_lanes([1, 3])
+        eng._push_lanes(pool, fstate, [1, 3], kv=True)
+        eng._push_lanes(pool, fstate, [1, 3], kv=False)
+        after = self._leaves(eng)
+        assert len(before) == len(after)
+        for a, b in zip(before, after):
+            assert a.dtype == b.dtype and a.tobytes() == b.tobytes()
+
+    def test_push_leaves_other_lanes_bit_identical(self, tiny_freeze):
+        eng = self._busy(tiny_freeze)
+        names = eng._POOL_FIELDS + eng._FZ_FIELDS
+        before = [np.array(a) for a in eng._state_arrs()]
+        pool, fstate = eng._pull_lanes([1, 3])
+        new = dict(pool, **fstate)
+        rng = np.random.RandomState(0)
+        for f in names:
+            a = new[f]
+            if a.dtype == bool:
+                new[f] = ~a
+            elif np.issubdtype(a.dtype, np.integer):
+                new[f] = a + 1
+            else:
+                new[f] = rng.standard_normal(a.shape).astype(a.dtype)
+        eng._push_lanes({f: new[f] for f in eng._POOL_FIELDS},
+                        {f: new[f] for f in eng._FZ_FIELDS}, [1, 3])
+        after = [np.array(a) for a in eng._state_arrs()]
+        for f, b, a in zip(names, before, after):
+            for lane in (0, 2):
+                assert a[:, lane].tobytes() == b[:, lane].tobytes(), f
+            for j, lane in enumerate((1, 3)):
+                assert a[:, lane].tobytes() == new[f][:, j].tobytes(), f
+                assert a[:, lane].tobytes() != b[:, lane].tobytes(), f
+
+    # greedy tokens of the run below, recorded when every pull and push
+    # still moved all n_lanes columns
+    RECORDED = [
+        [470, 187, 396, 8, 483, 47, 101, 302, 227, 336, 3, 355, 196, 84,
+         472, 302, 299, 215, 268, 128, 468],
+        [82, 337, 277, 394, 70, 83, 450, 355, 432, 478, 469, 215, 13, 244,
+         199, 469, 172, 124, 275, 493, 82, 50, 168, 105, 83, 471, 234, 195,
+         232, 60, 355, 187, 436, 450, 197, 193],
+        [474, 282, 242, 390, 249, 409, 493, 172, 25, 36, 154, 23, 403, 282,
+         108, 156, 372, 251, 157, 348, 460],
+        [124, 324, 87, 484, 114, 501, 290, 247, 386, 482, 124, 60, 211, 65,
+         312, 227, 342, 144, 207, 429, 380, 135, 135, 376, 401, 302, 450,
+         410, 450, 94],
+        [153, 184, 252, 104, 449, 226, 274, 153, 184, 209, 244, 382, 384, 32,
+         179, 161, 397, 48, 230, 352, 267, 483, 191, 260, 226],
+        [199, 475, 295, 342, 248, 165, 323, 310, 150, 318, 111, 206, 153,
+         258, 286, 414, 324, 435, 303],
+    ]
+
+    def test_greedy_tokens_match_the_recorded_run(self, tiny_freeze):
+        """Six greedy requests over four lanes: ticks of 1, 2 and 4 lanes
+        with pages swapped out and back in."""
+        eng = self._engine(tiny_freeze)
+        s = Scheduler(eng)
+        rng = np.random.RandomState(7)
+        uids = [s.submit(rng.randint(0, eng.cfg.vocab_size, size=sp), n,
+                         SamplingParams.greedy())
+                for sp, n in ((32, 21), (16, 36), (8, 21), (24, 30),
+                              (16, 25), (8, 19))]
+        ticks = []
+        tick = eng._boundary_tick
+
+        def spy(boundary):
+            ticks.append(len(boundary))
+            return tick(boundary)
+        eng._boundary_tick = spy
+        s.run()
+        assert set(ticks) == {1, 2, 4}
+        # the same swap schedule as the recorded run
+        assert (eng.ctl.n_swap_out, eng.ctl.n_swap_in) == (48, 27)
+        assert [s.done[u].result.tolist() for u in uids] == self.RECORDED
+
+    def test_one_program_per_direction_whatever_the_lanes(self, tiny_freeze):
+        eng = self._engine(tiny_freeze)
+        assert eng._gather_lane._cache_size() == 0
+        assert eng._scatter_lane._cache_size() == 0
+        for lanes in ([2], [0, 3], [0, 1, 2, 3]):
+            pool, fstate = eng._pull_lanes(lanes)
+            eng._push_lanes(pool, fstate, lanes, kv=True)
+        assert eng._gather_lane._cache_size() == 1
+        assert eng._scatter_lane._cache_size() == 1
+        # the metadata-only push is the one other field set
+        for lanes in ([2], [0, 3], [0, 1, 2, 3]):
+            pool, fstate = eng._pull_lanes(lanes)
+            eng._push_lanes(pool, fstate, lanes, kv=False)
+        assert eng._gather_lane._cache_size() == 1
+        assert eng._scatter_lane._cache_size() == 2

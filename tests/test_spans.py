@@ -39,10 +39,10 @@ def tiny():
     return cfg, MD.init_params(jax.random.PRNGKey(0), cfg)
 
 
-def _engine(tiny, kv_quant="none"):
+def _engine(tiny, kv_quant="none", n_lanes=2):
     cfg, params = tiny
     return PagedContinuousEngine(cfg, params, serving=ServingConfig(
-        max_seq=256, n_lanes=2, max_active_pages=4, prefill_chunk=16,
+        max_seq=256, n_lanes=n_lanes, max_active_pages=4, prefill_chunk=16,
         kv_quant=kv_quant))
 
 
@@ -137,53 +137,75 @@ def test_an_admission_is_tied_by_its_uid(tiny, tmp_path):
     assert len(push) == 1 and _inside(push[0], inst[0])
 
 
-@pytest.mark.parametrize("kv_quant", ["none", "int8"])
+@pytest.mark.parametrize("kv_quant,n_asked",
+                         [("none", 1), ("int8", 1), ("none", 2)],
+                         ids=["none", "int8", "none-2of4"])
 def test_b_transfer_bytes_are_the_bytes_that_crossed(tiny, tmp_path,
-                                                     kv_quant):
+                                                     kv_quant, n_asked):
     """The pull and push spans' ``bytes`` and the change in
-    ``TransferStats`` are the nbytes of the n_lanes-wide arrays that
-    crossed the bus; an int8 engine with quantized pages in the pool
-    takes no credit for a packing the transfer does not have."""
-    eng = _engine(tiny, kv_quant)
-    sched, lane = _admit(eng, 64, 40)
+    ``TransferStats`` are the nbytes of exactly the asked lanes' columns
+    (here 1 or 2 of 4 lanes), one gather and one scatter per lane; an int8
+    engine with quantized pages in the pool takes no credit for a packing
+    the transfer does not have."""
+    eng = _engine(tiny, kv_quant, n_lanes=4)
+    sched = Scheduler(eng)
+    rng = np.random.RandomState(0)
+    for _ in range(n_asked):
+        sched.submit(rng.randint(0, eng.cfg.vocab_size, size=64), 40,
+                     SamplingParams.greedy())
+    sched.step()
+    while eng.prefills:
+        sched.step()
     for _ in range(30):
         sched.step()
+    lanes = [i for i, l in enumerate(eng.lanes) if l.request is not None]
+    assert len(lanes) == n_asked
     if kv_quant == "int8":
         assert (np.asarray(eng.state.page_quant) != 0).any(), \
             "no quantized page in the pool: the check would be vacuous"
     eng.flush()
     crossed = {"pull": [], "push": []}
-    gather, scatter = eng._gather_lanes, eng._scatter_lanes
+    gather, scatter = eng._gather_lane, eng._scatter_lane
 
-    def spy_gather(arrs, idx):
-        out = gather(arrs, idx)
-        crossed["pull"].append(sum(a.nbytes for a in out))
+    def spy_gather(arrs, lane):
+        out = gather(arrs, lane)
+        crossed["pull"].append((int(lane), sum(a.nbytes for a in out)))
         return out
 
-    def spy_scatter(arrs, idx, vals):
-        crossed["push"].append(sum(v.nbytes for v in vals))
-        return scatter(arrs, idx, vals)
-    eng._gather_lanes, eng._scatter_lanes = spy_gather, spy_scatter
+    def spy_scatter(arrs, lane, vals):
+        crossed["push"].append((int(lane), sum(v.nbytes for v in vals)))
+        return scatter(arrs, lane, vals)
+    eng._gather_lane, eng._scatter_lane = spy_gather, spy_scatter
     st = eng.stats
     before = (st.d2h_bytes, st.h2d_bytes)
 
     def move():
-        pool, fstate = eng._pull_lanes([lane])
-        eng._push_lanes(pool, fstate, [lane], kv=True)
-        eng._push_lanes(pool, fstate, [lane], kv=False)
+        pool, fstate = eng._pull_lanes(lanes)
+        eng._push_lanes(pool, fstate, lanes, kv=True)
+        eng._push_lanes(pool, fstate, lanes, kv=False)
     spans, _, _ = _traced(tmp_path, move)
     pull = [s for s in spans if s.name == "engine.pull_lanes"]
     push = [s for s in spans if s.name == "engine.push_lanes"]
-    assert [s.stats["bytes"] for s in pull] == crossed["pull"]
-    assert [s.stats["bytes"] for s in push] == crossed["push"]
+    m = n_asked
+    # one program call per asked lane, on that lane
+    assert [c[0] for c in crossed["pull"]] == lanes
+    assert [c[0] for c in crossed["push"]] == lanes + lanes
+    pulled = sum(c[1] for c in crossed["pull"])
+    pushed = [sum(c[1] for c in crossed["push"][:m]),
+              sum(c[1] for c in crossed["push"][m:])]
+    assert [s.stats["bytes"] for s in pull] == [pulled]
+    assert [s.stats["bytes"] for s in push] == pushed
     assert [s.stats["kv"] for s in push] == [1, 0]
-    # all n_lanes columns cross, whatever the number of lanes asked for
-    assert crossed["pull"] == [sum(a.nbytes for a in eng._state_arrs())]
-    assert crossed["push"][0] == crossed["pull"][0]
-    assert crossed["push"][1] == sum(
-        a.nbytes for a in eng._state_arrs(eng._META_FIELDS))
-    assert st.d2h_bytes - before[0] == sum(crossed["pull"])
-    assert st.h2d_bytes - before[1] == sum(crossed["push"])
+    assert [s.stats["lanes"] for s in pull + push] == [m, m, m]
+
+    def columns(arrs):
+        # the asked lanes' columns of the device state, no more
+        return sum(a.nbytes // eng.n_lanes * m for a in arrs)
+    assert pulled == columns(eng._state_arrs())
+    assert pushed[0] == pulled
+    assert pushed[1] == columns(eng._state_arrs(eng._META_FIELDS))
+    assert st.d2h_bytes - before[0] == pulled
+    assert st.h2d_bytes - before[1] == sum(pushed)
 
 
 def test_the_paged_engine_programs_have_names(tiny, tmp_path):
@@ -193,8 +215,8 @@ def test_the_paged_engine_programs_have_names(tiny, tmp_path):
     from jax.profiler import ProfileData
     eng = _engine(tiny)
     want = {"_step": "decode_step_paged", "_sample": "sample_batched_perlane",
-            "_chunk": "prefill_chunk", "_gather_lanes": "gather_lanes",
-            "_scatter_lanes": "scatter_lanes", "_remap_copy": "remap_copy",
+            "_chunk": "prefill_chunk", "_gather_lane": "gather_lane",
+            "_scatter_lane": "scatter_lane", "_remap_copy": "remap_copy",
             "_stage_write": "stage_write", "_reset_lane": "reset_paged_lane",
             "_set_recovery": "set_recovery", "_rewind": "rewind_paged_lane"}
 

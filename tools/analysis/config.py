@@ -93,7 +93,7 @@ REPO_CONFIG = Config(
         "lane_state",
         "scratch",      # pp.scratch prefill cache
         "logits",
-        "dev",          # _pull_lanes' gathered device tuple
+        "dev",          # _pull_lanes' gathered per-lane device tuples
         "cache",        # KVCache pytrees handed to the offloader
         "info",         # decode_step telemetry pytree (pre-ring)
     }),
