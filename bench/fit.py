@@ -28,7 +28,7 @@ def fit(name: str, topo) -> dict:
     from repro.models import model as MD
 
     cell = H.load_cell(name)
-    cfg = H.model_config(cell.config)
+    cfg = H.model_config(cell.config, cell.root)
     s = cell.mix["serving"]
     dev = SingleDeviceSharding(topo.devices[0])
     # the decode step dispatches the Pallas kernel only on a TPU backend;

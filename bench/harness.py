@@ -3,6 +3,9 @@
 Everything a cell needs is found by name:
 
   bench/configs/<config>.json    the model configuration as run
+  bench/archs/<arch>.py          what its published keys mean: the
+                                 program's ``ModelConfig`` and the counts
+                                 ``bench/work`` needs (``architecture``)
   bench/references/<arch>.py     its plain reference (``architecture``)
   bench/traffic/<traffic>.json   the traffic mix and serving settings
   bench/metrics/<metric>.py      one per-layer metric's reader
@@ -19,6 +22,7 @@ from __future__ import annotations
 
 import asyncio
 import dataclasses
+import functools
 import gc
 import importlib.util
 import json
@@ -143,21 +147,30 @@ class CompileClock:
             self.n += 1
 
 
-def model_config(c: Dict):
-    """The ``ModelConfig`` a configuration file describes, with the freeze
-    schedule ``launch/serve.py``'s ``model_config`` gives its serve_arch."""
-    from repro.configs.base import ModelConfig
+def arch(c: Dict, root: pathlib.Path = ROOT):
+    """The architecture file the configuration ``c`` names
+    (``bench/archs/<architecture>.py`` under the checkout ``root``): the
+    only reader of a published configuration's own keys, for the program
+    and for the work counts."""
+    path = root / "bench" / "archs" / f"{c.get('architecture')}.py"
+    if not path.is_file():
+        raise SystemExit(f"bench: configuration {c.get('name')!r} needs the "
+                         f"architecture file {path}, which does not exist")
+    return _load_arch(path)
+
+
+@functools.lru_cache(maxsize=None)
+def _load_arch(path: pathlib.Path):
+    return load_module(path)
+
+
+def model_config(c: Dict, root: pathlib.Path = ROOT):
+    """The ``ModelConfig`` a configuration file describes, built by its
+    architecture file, with the freeze schedule ``launch/serve.py``'s
+    ``model_config`` gives its serve_arch."""
     from repro.launch import serve
     freeze = serve.model_config(c["serve_arch"]).freeze
-    return ModelConfig(
-        name=c["name"], arch_type="dense",
-        num_layers=c["num_hidden_layers"], d_model=c["hidden_size"],
-        num_heads=c["num_attention_heads"],
-        num_kv_heads=c["num_key_value_heads"], d_ff=c["intermediate_size"],
-        vocab_size=c["vocab_size"], head_dim=c["head_dim"],
-        rope_theta=c["rope_theta"], norm_eps=c["rms_norm_eps"],
-        dtype=c["torch_dtype"], tie_embeddings=c["tie_word_embeddings"],
-        source=c["source"], freeze=freeze)
+    return arch(c, root).model_config(c, freeze)
 
 
 def make_params(c: Dict, cfg, seed: int):

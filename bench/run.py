@@ -70,7 +70,7 @@ def main(argv=None, require_tpu: bool = True, root=None) -> dict:
     H.log(f"device {device}; compile cache {serve.enable_compile_cache()}")
     clock = H.CompileClock()
 
-    cfg = H.model_config(cell.config)
+    cfg = H.model_config(cell.config, cell.root)
     mix = cell.mix
     params = H.make_params(cell.config, cfg, H.weight_seed(args.seed))
     eng = serve.build_engine(cfg, params, H.serving_config(mix, args.seed))

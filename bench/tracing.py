@@ -2,12 +2,20 @@
 numbers the per-layer metrics read.
 
 A trace is reduced from a flat list of events ``(plane, line, name,
-start_ns, dur_ns)``, so the reduction can be checked on a small recorded
-list without a chip.  Device events are those on planes named
-``/device:TPU:<n>``; the harness's own host spans are the events named
-``bench:<span>`` on the host planes, written by
+start_ns, dur_ns[, thread, stats])``, so the reduction can be checked on a
+small recorded list without a chip.  Device events are those on planes
+named ``/device:TPU:<n>``; the harness's own host spans are the events
+named ``bench:<span>`` on the host planes, written by
 ``jax.profiler.TraceAnnotation`` on the same clock.  ``bench:window``
 brackets the measured window.
+
+The program's own spans, ``repro:<layer>.<phase>`` on the host planes
+(docs/serving.md), are kept with their thread (``<line>/<index>``, as
+threads may share a name) and their stats, the keyword metadata the
+program gave them (``bytes=``, ``lanes=``, ...): a counter the program
+adds reaches a per-layer metric's reader as a span's stat.  The
+arithmetic on them is copied from ``repro.analysis.spans``, so the
+yardstick stays with the benchmark.
 """
 from __future__ import annotations
 
@@ -18,7 +26,9 @@ import os
 import re
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
-Event = Tuple[str, str, str, float, float]    # plane, line, name, start, dur
+Event = Tuple                   # plane, line, name, start, dur[, thread,
+                                # stats] (the last two on program spans)
+Span = collections.namedtuple("Span", "name start dur thread stats")
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 # the device line whose events are single operations (XLA's op line);
@@ -26,6 +36,7 @@ DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 WINDOW = "bench:window"
+PROGRAM = "repro:"               # the program's spans
 
 
 def start(log_dir: str) -> None:
@@ -42,7 +53,8 @@ def stop() -> None:
 
 
 def load(log_dir: str) -> List[Event]:
-    """Every event of the newest ``.xplane.pb`` under ``log_dir``."""
+    """Every event of the newest ``.xplane.pb`` under ``log_dir``; the
+    program's spans also with their thread and stats."""
     from jax.profiler import ProfileData
     paths = sorted(glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
                              recursive=True), key=os.path.getmtime)
@@ -50,10 +62,14 @@ def load(log_dir: str) -> List[Event]:
         raise FileNotFoundError(f"no .xplane.pb under {log_dir}")
     out: List[Event] = []
     for plane in ProfileData.from_file(paths[-1]).planes:
-        for line in plane.lines:
+        device = bool(DEVICE_PLANE.match(plane.name))
+        for i, line in enumerate(plane.lines):
             for e in line.events:
-                out.append((plane.name, line.name, short_name(e.name),
-                            float(e.start_ns), float(e.duration_ns)))
+                ev = (plane.name, line.name, short_name(e.name),
+                      float(e.start_ns), float(e.duration_ns))
+                if not device and e.name.startswith(PROGRAM):
+                    ev += (f"{line.name}/{i}", dict(e.stats))
+                out.append(ev)
     return out
 
 
@@ -68,11 +84,11 @@ def excerpt(events: Sequence[Event], ms: float, path: str,
     """Write the events of the window's first ``ms`` milliseconds (the
     window span cut to that length) with ``meta`` as JSON."""
     import json
-    lo = next(s for p, _, n, s, d in events
+    lo = next(s for p, _, n, s, d, *_ in events
               if n == WINDOW and not DEVICE_PLANE.match(p))
     hi = lo + ms * 1e6
-    keep = [[p, line, n, s - lo, min(d, hi - s)]
-            for p, line, n, s, d in events
+    keep = [[p, line, n, s - lo, min(d, hi - s), *rest]
+            for p, line, n, s, d, *rest in events
             if lo <= s < hi and n != WINDOW]
     keep.append(["/host:CPU", "python", WINDOW, 0.0, hi - lo])
     with open(path, "w") as f:
@@ -105,6 +121,7 @@ class Reduced:
     module_calls: Dict[str, int]
     holding: Dict[str, set]                 # program name -> op names run
                                             # inside its executions
+    spans: List[Span]                       # the program's spans, by start
 
     @property
     def window_s(self) -> float:
@@ -139,6 +156,37 @@ class Reduced:
         return (sum(self.module_ns[k] for k in names) / 1e9,
                 sum(self.module_calls[k] for k in names))
 
+    # ---- the program's spans that start in the window (None where it
+    # holds no such span); as ``repro.analysis.spans`` computes them ----
+    def _named(self, name: str) -> List[Span]:
+        lo, hi = self.window_ns
+        return [s for s in self.spans if s.name == name and lo <= s.start < hi]
+
+    def span_ms(self, name: str) -> Optional[float]:
+        """Mean duration of the span ``name``, ms."""
+        got = self._named(name)
+        return sum(s.dur for s in got) / len(got) / 1e6 if got else None
+
+    def span_gbps(self, name: str, **match) -> Optional[float]:
+        """Bytes moved per second inside the span ``name`` (its ``bytes``
+        stat over its duration, summed over the spans whose stats equal
+        ``match``), GB/s."""
+        got = [s for s in self._named(name)
+               if all(s.stats.get(k) == v for k, v in match.items())]
+        t = sum(s.dur for s in got)              # bytes per ns = GB/s
+        return sum(s.stats["bytes"] for s in got) / t if got and t else None
+
+    def plain_step_ms(self) -> Optional[float]:
+        """Mean duration of the engine steps that hold no boundary tick,
+        ms."""
+        ticks = [s for s in self.spans if s.name == "engine.tick"]
+
+        def holds_tick(step):
+            return any(t.thread == step.thread and step.start <= t.start
+                       < step.start + step.dur for t in ticks)
+        got = [s for s in self._named("engine.step") if not holds_tick(s)]
+        return sum(s.dur for s in got) / len(got) / 1e6 if got else None
+
     def breakdown(self, k: int = 10) -> Dict[str, List]:
         ops = sorted(self.op_ns.items(), key=lambda kv: -kv[1])[:k]
         gaps = sorted(self.gaps, key=lambda g: -(g[1] - g[0]))[:k]
@@ -149,16 +197,23 @@ class Reduced:
 def reduce(events: Sequence[Event]) -> Reduced:
     """Busy union, idle gaps (each labelled with the innermost harness
     span open at its midpoint), and device time by op and by program,
-    all within the ``bench:window`` span."""
-    win = [(s, s + d) for p, _, n, s, d in events
+    all within the ``bench:window`` span; and every program span of the
+    capture (the capture brackets the window; the span readers take the
+    ones that start inside it)."""
+    win = [(s, s + d) for p, _, n, s, d, *_ in events
            if n == WINDOW and not DEVICE_PLANE.match(p)]
     if not win:
         raise ValueError("the trace holds no bench:window span")
     lo, hi = win[0]
     devices = sorted({p for p, *_ in events if DEVICE_PLANE.match(p)})
-    spans = sorted(((s, s + d, n[len("bench:"):]) for p, _, n, s, d in events
+    spans = sorted(((s, s + d, n[len("bench:"):])
+                    for p, _, n, s, d, *_ in events
                     if n.startswith("bench:") and n != WINDOW
                     and not DEVICE_PLANE.match(p)), key=lambda x: x[0])
+    program = sorted((Span(n[len(PROGRAM):], s, d, *rest)
+                      for p, _, n, s, d, *rest in events
+                      if rest and n.startswith(PROGRAM)),
+                     key=lambda x: x.start)
     op_ns: Dict[str, float] = collections.defaultdict(float)
     op_calls: Dict[str, int] = collections.defaultdict(int)
     mod_ns: Dict[str, float] = collections.defaultdict(float)
@@ -169,7 +224,7 @@ def reduce(events: Sequence[Event]) -> Reduced:
     for dev in devices:
         ivs = []
         mods = []
-        for p, line, n, s, d in events:
+        for p, line, n, s, d, *_ in events:
             if p != dev or not (lo <= s < hi):
                 continue
             if line == OPS_LINE:
@@ -192,7 +247,7 @@ def reduce(events: Sequence[Event]) -> Reduced:
     n = max(len(devices), 1)
     return Reduced((lo, hi), len(devices), busy_total / n, gaps,
                    dict(op_ns), dict(op_calls), dict(mod_ns),
-                   dict(mod_calls), dict(holding))
+                   dict(mod_calls), dict(holding), program)
 
 
 def _attribute(mods, ops, holding) -> None:

@@ -2,7 +2,10 @@
 needs for its calls: the K and V of the tokens it can see, q and the
 output, and the per-page tables; not the bytes the kernel happens to
 fetch.  Attention is memory bound at these shapes (H/KVH multiply-adds per
-byte of K/V), so the bound is reported with the share."""
+byte of K/V), so the bound is reported with the share.  The per-token and
+per-lane counts come from the configuration's architecture file
+(``bench/archs/<architecture>.py``)."""
+from harness import arch
 
 # the kernel's op in a device trace: the Pallas call's custom-call takes
 # the kernel function's name (``paged_decode_attention_kernel.<n>``)
@@ -14,15 +17,14 @@ def work(c, *, visible: float, calls: int, lanes: int, pages: int,
     """``visible``: tokens attended summed over calls and lanes; ``calls``
     kernel invocations (one per layer and step), each over ``lanes`` lanes
     of ``pages`` physical page slots of ``page`` tokens."""
-    H, KVH, hd = (c["num_attention_heads"], c["num_key_value_heads"],
-                  c["head_dim"])
-    flops = 4.0 * H * hd * visible
-    kv = visible * KVH * hd * 2 * kv_bytes
-    per_call = (2 * lanes * H * hd * kv_bytes          # q in, output out
+    a = arch(c)
+    flops = a.attn_flops_per_token(c) * visible
+    kv = visible * a.kv_bytes_per_token(c, kv_bytes)
+    per_call = (lanes * a.q_out_bytes_per_lane(c, kv_bytes)  # q in, out
                 + lanes * pages * 4                     # relevance out
                 + lanes * pages * (4 + 1 + 4)           # table, visible, quant
                 + lanes * pages * page                  # slot mask
-                + lanes * pages * 2 * KVH * 4)          # K/V scales
+                + lanes * pages * a.kv_scale_bytes_per_page(c))  # K/V scales
     return {"flops": flops, "bytes": kv + calls * per_call}
 
 

@@ -1,9 +1,10 @@
 """Shared set-up for the benchmark's CPU tests: the harness modules under
 ``bench/`` on the import path, and a throwaway checkout holding a tiny
-cell (BENCHMARK.json, configuration, traffic and limits) that
-``bench/run.py``'s ``main`` can run on the CPU."""
+cell (BENCHMARK.json, configuration, its architecture file, traffic and
+limits) that ``bench/run.py``'s ``main`` can run on the CPU."""
 import json
 import pathlib
+import shutil
 import sys
 
 import pytest
@@ -57,6 +58,7 @@ def tiny_root(tmp_path_factory) -> pathlib.Path:
     for d in ("configs", "traffic", "limits"):
         (root / "bench" / d).mkdir(parents=True)
     (root / "bench/configs/tiny.json").write_text(json.dumps(TINY_CONFIG))
+    shutil.copytree(ROOT / "bench/archs", root / "bench/archs")
     workloads = []
     for name, mix in TINY_MIXES.items():
         (root / f"bench/traffic/{name}.json").write_text(json.dumps(mix))

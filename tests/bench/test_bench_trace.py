@@ -59,7 +59,8 @@ def test_busy_union_gaps_and_device_time():
 
 def test_metric_readers_on_the_reduced_trace():
     r = tracing.reduce(events())
-    cfg = {"num_attention_heads": 4, "num_key_value_heads": 2,
+    cfg = {"architecture": "mistral",
+           "num_attention_heads": 4, "num_key_value_heads": 2,
            "head_dim": 64, "hidden_size": 256, "intermediate_size": 512,
            "num_hidden_layers": 2, "vocab_size": 512}
     run = H.Run([], t0=0.0, t1=0.1, lane_steps=4, visible=2000.0,

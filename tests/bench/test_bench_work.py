@@ -12,8 +12,8 @@ ROOT = pathlib.Path(__file__).resolve().parents[2]
 
 # Mistral-Large-Instruct-2407's published widths at 3 of 88 layers, the
 # second configuration the decode-step count is written for
-LARGE = {"hidden_size": 12288, "num_attention_heads": 96,
-         "num_key_value_heads": 8, "head_dim": 128,
+LARGE = {"architecture": "mistral", "hidden_size": 12288,
+         "num_attention_heads": 96, "num_key_value_heads": 8, "head_dim": 128,
          "intermediate_size": 28672, "num_hidden_layers": 3,
          "vocab_size": 32768}
 
